@@ -197,6 +197,7 @@ def fig7_cores(n=30_000, d=4):
             f"--xla_force_host_platform_device_count={devices}")
         env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                          "src")
+        env["JAX_PLATFORMS"] = "cpu"
         r = subprocess.run([sys.executable, "-c", code],
                            capture_output=True, text=True, env=env,
                            timeout=900)

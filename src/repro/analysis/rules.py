@@ -61,10 +61,10 @@ RULES = {
         "and call through the spec."),
     "R4": Rule(
         "R4", "shard_map/Mesh imports only through repro.compat",
-        "jax.experimental.shard_map moved across JAX releases; "
-        "repro/compat.py is the one shim that tracks it (and the "
-        "mesh-construction API). A raw import elsewhere breaks one of "
-        "the two supported JAX versions.",
+        "repro/compat.py is the one import point for shard_map and "
+        "the mesh-construction API, so the repo's sharding conventions "
+        "(Auto axis types, check_vma off) live in one place. A raw "
+        "import elsewhere bypasses them.",
         "from repro.compat import shard_map, make_mesh, set_mesh."),
     "R5": Rule(
         "R5", "no Python branching on traced values in core/ hot paths",

@@ -1,87 +1,35 @@
-"""Version-portable wrappers over jax APIs that moved between releases.
+"""The one import point for jax's sharding APIs.
 
-The repo targets the current jax API (``jax.shard_map``,
-``jax.sharding.set_mesh``, ``jax.make_mesh(axis_types=...)``) but must
-also run on jax 0.4.x, where shard_map lives in
-``jax.experimental.shard_map`` (with ``check_rep`` instead of
-``check_vma``), meshes have no axis types, and there is no ambient-mesh
-setter beyond the legacy ``with mesh:`` context. Every call site in the
-repo goes through this module so the divergence lives in exactly one
-place.
+Every call site in the repo takes ``shard_map``, ``make_mesh`` and
+``set_mesh`` from here (skylint rule R4), so the repo's sharding
+conventions — Auto axis types on every mesh, ``check_vma`` off by
+default — live in exactly one place.
 """
 
 from __future__ import annotations
 
-import contextlib
-from typing import Any
-
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["shard_map", "make_mesh", "set_mesh", "ambient_mesh"]
-
-_HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_SET_MESH = hasattr(jax.sharding, "set_mesh")
-
-try:  # axis types exist only on newer jax
-    from jax.sharding import AxisType as _AxisType  # noqa: F401
-    _HAS_AXIS_TYPES = True
-except ImportError:
-    _AxisType = None
-    _HAS_AXIS_TYPES = False
-
-
-def ambient_mesh():
-    """The mesh installed by :func:`set_mesh` (None when unset)."""
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # pragma: no cover — internal layout changed
-        return None
+__all__ = ["shard_map", "make_mesh", "set_mesh"]
 
 
 def shard_map(f, mesh=None, *, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` on new jax; the experimental one on 0.4.x.
-
-    ``check_vma`` maps onto the old ``check_rep`` flag. ``mesh=None``
-    resolves to the ambient mesh installed by :func:`set_mesh` (the new
-    API does this natively; on old jax we look it up explicitly).
-    """
-    if _HAS_NEW_SHARD_MAP:
-        kwargs: dict[str, Any] = dict(in_specs=in_specs,
-                                      out_specs=out_specs,
-                                      check_vma=check_vma)
-        if mesh is not None:
-            kwargs["mesh"] = mesh
-        return jax.shard_map(f, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _sm
-    mesh = mesh if mesh is not None else ambient_mesh()
-    if mesh is None:
-        raise ValueError(
-            "shard_map without an explicit mesh requires an ambient mesh "
-            "(repro.compat.set_mesh) on this jax version")
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """``jax.shard_map``; ``mesh=None`` uses the ambient mesh installed
+    by :func:`set_mesh`."""
+    kwargs = dict(in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check_vma)
+    if mesh is not None:
+        kwargs["mesh"] = mesh
+    return jax.shard_map(f, **kwargs)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported."""
-    kwargs: dict[str, Any] = {}
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    kwargs = {"axis_types": (AxisType.Auto,) * len(tuple(axis_names))}
     if devices is not None:
         kwargs["devices"] = devices
-    if _HAS_AXIS_TYPES:
-        kwargs["axis_types"] = (_AxisType.Auto,) * len(tuple(axis_names))
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
 
 
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """Ambient-mesh context: ``jax.sharding.set_mesh`` on new jax, the
-    legacy ``with mesh:`` resource context on 0.4.x."""
-    if _HAS_SET_MESH:
-        with jax.sharding.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+set_mesh = jax.sharding.set_mesh

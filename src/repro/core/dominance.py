@@ -69,10 +69,17 @@ def canonical_order(pts: jnp.ndarray, mask: jnp.ndarray | None = None
     so any tie order is a valid SFS topological order; fixing it
     lexicographically is what makes canonicalized buffers bitwise
     comparable across execution paths (one-shot vs any chunking —
-    repro.core.incremental relies on this). Invalid rows sort last."""
+    repro.core.incremental relies on this). Invalid rows sort last.
+
+    Computed as d+1 stable single-key sorts, least significant key
+    first — exactly the permutation of one multi-key lexsort, which
+    XLA's TPU compiler takes minutes over (318 s for a vmapped 5-key
+    sort of 32 x 4,096 rows, against 16 s this way)."""
     score = monotone_score(pts, mask)
-    keys = tuple(pts[:, j] for j in reversed(range(pts.shape[1])))
-    return jnp.lexsort(keys + (score,))
+    order = jnp.arange(pts.shape[0], dtype=jnp.int32)
+    for key in [pts[:, j] for j in reversed(range(pts.shape[1]))] + [score]:
+        order = order[jnp.argsort(key[order])]
+    return order
 
 
 def apply_sentinel(pts: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:

@@ -134,8 +134,19 @@ def compact_order(mask: jnp.ndarray, capacity: int) -> jnp.ndarray:
     """The row order `compact` gathers by: stable valid-rows-first,
     truncated to ``capacity``.  Exposed so callers carrying side columns
     (partition ids, grid cells) can reorder them identically and share
-    `compact`'s overflow accounting."""
-    return jnp.argsort(jnp.logical_not(mask))[:capacity]
+    `compact`'s overflow accounting.
+
+    A counting pass — each row's destination from two prefix sums, then
+    one scatter — gives exactly the permutation of a stable argsort of
+    ``~mask`` without a sort: XLA's TPU compiler spends tens of seconds
+    on each sort of tens of thousands of rows, and this runs in every
+    merge and state update."""
+    n = mask.shape[0]
+    m = mask.astype(jnp.int32)
+    dest = jnp.where(mask, jnp.cumsum(m) - 1,
+                     jnp.sum(m) + jnp.cumsum(1 - m) - 1)
+    return jnp.zeros((min(n, capacity),), jnp.int32).at[dest].set(
+        jnp.arange(n, dtype=jnp.int32), mode="drop", unique_indices=True)
 
 
 def compact(pts: jnp.ndarray, mask: jnp.ndarray, capacity: int) -> SkyBuffer:

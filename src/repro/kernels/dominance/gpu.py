@@ -40,8 +40,8 @@ def _dominance_gpu_kernel(cands_ref, refs_ref, mask_ref, out_ref, *,
     x = cands_ref[...]  # (d_pad, BC)
 
     def body(j, acc):
-        r = pl.load(refs_ref, (slice(None), pl.ds(j * block_r, block_r)))
-        m = pl.load(mask_ref, (slice(None), pl.ds(j * block_r, block_r)))
+        r = refs_ref[:, pl.ds(j * block_r, block_r)]
+        m = mask_ref[:, pl.ds(j * block_r, block_r)]
         return acc | _block_dominated(
             x, r, m, d=d, block_c=block_c, block_r=block_r,
             lower_tri=lower_tri, roff=j * block_r, coff=i * block_c)

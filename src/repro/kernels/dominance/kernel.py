@@ -20,9 +20,10 @@ Grid: ``(num_cand_blocks, num_ref_blocks)`` with the ref-block index
 innermost, so each output tile stays resident while it accumulates the
 OR over all reference blocks.
 
-VMEM per step (defaults BC=BR=512, d_pad=8, fp32):
-  cands tile 512*8*4 = 16 KiB, refs tile 16 KiB, mask 2 KiB, out 2 KiB,
-  (BR, BC) intermediates 512*512*4 = 1 MiB  -> comfortably < 16 MiB VMEM.
+VMEM per step (defaults BC=BR=512, d_pad=8, fp32): 2.1 MiB as compiled
+by Mosaic for a v5e, mostly the per-attribute (BR, 1) reference columns
+and the (BR, BC) masks (`dominance_vmem_bytes` bounds it) — well under
+the 16 MiB default.
 """
 
 from __future__ import annotations
@@ -125,19 +126,22 @@ def dominated_mask_pallas(
         ],
         out_specs=pl.BlockSpec((1, block_c), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, c), jnp.int32),
+        name="dominated_mask",
         interpret=interpret,
     )(cands_t, refs_t, ref_mask)
 
 
 def dominance_vmem_bytes(*, block_c: int, block_r: int,
                          itemsize: int = 4) -> int:
-    """Static per-grid-step VMEM footprint estimate for the dominance
-    kernel: the two attribute tiles plus the ``(BR, BC)`` le/lt test
-    intermediates (booleans at one byte, iota comparisons fused — see
-    `repro.kernels.sfs.kernel.sweep_vmem_bytes` for the accounting
-    conventions). Gated per compiled configuration by the static
-    verifier (`repro.analysis`)."""
-    io = D_PAD * (block_c + block_r) * itemsize \
-        + (block_r + block_c) * 4               # mask + out (int32)
-    tests = 2 * block_r * block_c               # le, lt (bool)
-    return io + tests
+    """Upper bound on the dominance kernel's scoped VMEM, in bytes: the
+    double-buffered attribute/mask/output blocks, each attribute's
+    ``(BR, 1)`` reference column at a full 128-lane row of 32-bit words,
+    and the ``(BR, BC)`` le/lt masks at 32 bits (the accounting of
+    `repro.kernels.sfs.kernel.sweep_vmem_bytes`).  Mosaic needs 2.1 MiB
+    at BR = BC = 512 (compiled for a described v5e).  Gated per
+    compiled configuration by the static verifier (`repro.analysis`)."""
+    io = 2 * (D_PAD * (block_c + block_r) * itemsize
+              + 8 * (block_r + block_c) * 4)    # + mask, out (int32)
+    columns = D_PAD * block_r * 128 * 4
+    tests = 2 * 4 * block_r * block_c           # le, lt
+    return io + columns + tests

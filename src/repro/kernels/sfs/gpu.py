@@ -43,17 +43,15 @@ from repro.kernels.sfs.kernel import D_PAD, _tiled_block_step
 __all__ = ["sfs_sweep_pallas_gpu"]
 
 
-def _sfs_sweep_gpu_kernel(cands_ref, mask_ref, win_ref, wmask_ref,
-                          count_ref, *, d: int, block_c: int, nblocks: int,
-                          wcap: int, wtile: int, sentinel):
-    win_ref[...] = jnp.full_like(win_ref, sentinel)
-    wmask_ref[...] = jnp.zeros_like(wmask_ref)
+def _sfs_sweep_gpu_kernel(cands_ref, mask_ref, win_ref, count_ref, *,
+                          d: int, block_c: int, nblocks: int, wcap: int,
+                          wtile: int, sentinel):
+    win_ref[...] = jnp.full(win_ref.shape, sentinel, win_ref.dtype)
 
     def cbody(j, count):
-        x = pl.load(cands_ref, (slice(None), pl.ds(j * block_c, block_c)))
-        xm = pl.load(mask_ref,
-                     (slice(None), pl.ds(j * block_c, block_c)))[0, :] > 0
-        return _tiled_block_step(x, xm, count, win_ref, wmask_ref, d=d,
+        x = cands_ref[:, pl.ds(j * block_c, block_c)]
+        xm = mask_ref[:, pl.ds(j * block_c, block_c)][0, :] > 0
+        return _tiled_block_step(x, xm, count, win_ref, d=d,
                                  block_c=block_c, wcap=wcap, wtile=wtile)
 
     count_ref[0, 0] = jax.lax.fori_loop(0, nblocks, cbody, jnp.int32(0))
@@ -71,7 +69,7 @@ def sfs_sweep_pallas_gpu(
     sentinel: float,
     wtile: int = 0,
     interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fused SFS sweep, one GPU program per partition.
 
     Same contract as :func:`repro.kernels.sfs.kernel.sfs_sweep_pallas`
@@ -92,7 +90,7 @@ def sfs_sweep_pallas_gpu(
     kernel = functools.partial(
         _sfs_sweep_gpu_kernel, d=d_pad, block_c=block_c,
         nblocks=n // block_c, wcap=wcap, wtile=wtile, sentinel=sentinel)
-    return pl.pallas_call(
+    win_t, count = pl.pallas_call(
         kernel,
         grid=(p,),
         in_specs=[
@@ -101,13 +99,12 @@ def sfs_sweep_pallas_gpu(
         ],
         out_specs=[
             pl.BlockSpec((d_pad, wcap), lambda i: (i, 0)),
-            pl.BlockSpec((1, wcap), lambda i: (i, 0)),
             pl.BlockSpec((1, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((pd_pad, wcap), cands_t.dtype),
-            jax.ShapeDtypeStruct((p, wcap), jnp.int32),
             jax.ShapeDtypeStruct((p, 1), jnp.int32),
         ],
         interpret=interpret,
     )(cands_t, mask)
+    return win_t, count[:, 0]

@@ -2,11 +2,11 @@
 
 One ``pallas_call`` executes the *entire* sorted Sort-Filter-Skyline scan
 for a batch of partitions: grid ``(partition, candidate_block)`` with the
-candidate-block index innermost, so each partition's window buffer, window
-mask and running count stay resident in on-chip memory across its whole
-scan (they are carried in the revisited output blocks — the same residency
-trick the blocked dominance kernel uses for its OR-accumulator — with the
-count in SMEM).  This replaces the seed's one-kernel-dispatch-per
+candidate-block index innermost, so each partition's window buffer and
+running count stay resident in on-chip memory across its whole scan (the
+window in a revisited output block — the same residency trick the
+blocked dominance kernel uses for its OR-accumulator — the count in
+SMEM scratch).  This replaces the seed's one-kernel-dispatch-per
 (window-block, candidate-block) pair inside an XLA ``fori_loop``: the
 window test, the lower-triangular in-block self-test and the append are
 fused into a single kernel body, so a whole partition batch is one launch
@@ -28,21 +28,28 @@ Semantics are bit-for-bit those of the per-pair reference
 body): identical keep decisions, identical slot assignment (first ``W``
 keeps in score order, later keeps dropped), identical running count.
 
-VMEM note (the tiling contract new backends must keep): untiled
-(``wtile=0``) the window test materializes ``(W, BC)`` intermediates and
-the append a ``(BC, W)`` one-hot, so ``W * BC`` elements must fit in VMEM
-alongside the ``(d_pad, W)`` window — comfortable for the serving-regime
-defaults (W <= 4096, BC <= 512, fp32: < 10 MiB).  With ``wtile=T`` the
-window test and the append iterate over W/T window sub-blocks
-(`_tiled_block_step`), so the materialized intermediates shrink to
-``T * BC`` elements and the resident footprint is O(T x BC) no matter the
-capacity — only the (small, ``d_pad * W``) window buffer itself scales
-with W.  `sweep_vmem_bytes` states both laws in bytes and the static
-verifier (`repro.analysis`) gates every compiled configuration against
-the 16 MiB/core cap; all tilings are bit-for-bit identical (the tile only
-changes the schedule, never a keep decision).  On real TPUs ``wtile``
-should be a multiple of the 128-wide lane tile for aligned dynamic
-slices.  Interpret mode (the CPU validation path) has no such limits.
+Mosaic layout rules the kernel keeps: the running count is a scalar in
+SMEM (scratch), written out as one lane row per partition; the mask and
+count blocks carry a squeezed leading partition dim so their last two
+block dims equal the array's for any P; the window's validity mask is
+not an output at all (the window is packed, so the caller derives it
+from the count).
+
+VMEM (the tiling contract new backends must keep): untiled (``wtile=0``)
+the window test materializes ``(W, BC)`` masks and, per attribute, a
+``(W, 1)`` window column that Mosaic lays out as a full 128-lane row —
+about 4 KiB per window slot, so W=4096 needs 16.2 MiB at BC=256.  With
+``wtile=T`` the window test and the append iterate over W/T window
+sub-blocks (`_tiled_block_step`), so the intermediates shrink to the
+T-slot tile and only the ``(d_pad, W)`` window buffer itself scales
+with W (1.4 MiB at W=4096, T=512; 8 MiB at W=262144).
+`sweep_vmem_bytes` bounds both laws; the kernel asks Mosaic for that
+bound as its scoped-VMEM limit when it exceeds the default, and the
+compiled TPU path always tiles windows wider than one tile
+(`repro.kernels.sfs.ops.tpu_geometry`).  Dynamic window slices start
+on a multiple of the 128-lane tile there.  All tilings are bit-for-bit
+identical (the tile only changes the schedule, never a keep decision).
+Interpret mode (the CPU validation path) has no such limits.
 """
 
 from __future__ import annotations
@@ -52,10 +59,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["sfs_sweep_pallas", "sweep_vmem_bytes", "D_PAD"]
+__all__ = ["sfs_sweep_pallas", "sweep_vmem_bytes", "vmem_limit_bytes",
+           "D_PAD", "LANE"]
 
-D_PAD = 8  # attribute dim padded to one fp32 sublane tile
+D_PAD = 8   # attribute dim padded to one fp32 sublane tile
+LANE = 128  # TPU lane width: dynamic window slices start on a multiple
 
 
 def _self_test(x, *, d: int, block_c: int):
@@ -74,12 +84,66 @@ def _self_test(x, *, d: int, block_c: int):
     return jnp.any(le_s & lt_s & (rid < cid), axis=0)
 
 
-def _tiled_block_step(x, xm, count, win_ref, wmask_ref, *, d: int,
-                      block_c: int, wcap: int, wtile: int):
+def _append(x, pos, base, width: int, cur):
+    """Rows of the ``width``-slot window sub-block starting at slot
+    ``base`` after the append: candidate ``c`` lands in slot ``pos[c]``
+    (-1 for candidates not kept).  Scatter-free: a one-hot
+    ``(BC, width)`` slot map routes each kept candidate with a masked
+    sum over the INTEGER BITS of its values — exactly one non-zero
+    contributor per slot and integer addition is exact, so the copy
+    preserves every bit (including -0.0, which a float sum would flip to
+    +0.0).  Keeps past the window match no slot and are dropped (the
+    reference's ``mode="drop"``)."""
+    block_c = x.shape[1]
+    slot = base + jax.lax.broadcasted_iota(jnp.int32, (block_c, width), 1)
+    onehot = pos[:, None] == slot                            # (BC, width)
+    newrow = jnp.any(onehot, axis=0)                         # (width,)
+    ibits = {4: jnp.int32, 2: jnp.int16, 1: jnp.int8}[
+        jnp.dtype(x.dtype).itemsize]
+    izero = jnp.zeros((), ibits)
+    rows = []
+    for k in range(x.shape[0]):
+        xb = jax.lax.bitcast_convert_type(x[k, :], ibits)    # (BC,)
+        vals = jnp.sum(jnp.where(onehot, xb[:, None], izero), axis=0)
+        row = jax.lax.bitcast_convert_type(vals, x.dtype)    # (width,)
+        rows.append(jnp.where(newrow, row, cur[k, :]))
+    return jnp.stack(rows)
+
+
+def _keep_slots(x, xm, domw, count, *, d: int, block_c: int):
+    """Window slot of each candidate of one block that is kept (valid,
+    not dominated by the window, not dominated within the block) —
+    ``count + |kept earlier in block|`` — or -1, and the number kept.
+    The in-block prefix count is a (BC, BC) masked reduction (no cumsum
+    primitive needed on the lane axis)."""
+    keep = xm & ~domw & ~_self_test(x, d=d, block_c=block_c)
+    ki = keep.astype(jnp.int32)
+    rid = jax.lax.broadcasted_iota(jnp.int32, (block_c, block_c), 0)
+    cid = jax.lax.broadcasted_iota(jnp.int32, (block_c, block_c), 1)
+    prefix = jnp.sum(jnp.where(rid <= cid, ki[:, None], 0), axis=0)
+    return jnp.where(keep, count + prefix - 1, -1), jnp.sum(ki)
+
+
+def _window_dominated(w, x, *, d: int):
+    """(BC,) bool: candidate dominated by a member of the ``(d_pad, T)``
+    window tile ``w``.  No validity mask: empty slots hold the sentinel
+    coordinate in every attribute and cannot dominate data below it."""
+    le = None
+    lt = None
+    for k in range(d):  # unrolled: d is a static 2..8
+        wk = w[k, :][:, None]    # (T, 1)
+        xk = x[k, :][None, :]    # (1, BC)
+        le = (wk <= xk) if le is None else le & (wk <= xk)
+        lt = (wk < xk) if lt is None else lt | (wk < xk)
+    return jnp.any(le & lt, axis=0)
+
+
+def _tiled_block_step(x, xm, count, win_ref, *, d: int, block_c: int,
+                      wcap: int, wtile: int):
     """One candidate-block step of the sweep with the window iterated in
     ``wtile``-column sub-blocks — the SHARED kernel body of the tiled TPU
     path and the GPU backend (gpu.py), which both hold the window in a
-    ``(d_pad, W)`` / ``(1, W)`` ref pair revisited across the scan.
+    ``(d_pad, W)`` ref revisited across the scan.
 
     Never materializes more than ``wtile * block_c`` test elements at
     once: the window test is a fori_loop over the live tiles (slots past
@@ -88,137 +152,60 @@ def _tiled_block_step(x, xm, count, win_ref, wmask_ref, *, d: int,
     [count, count+kept) intersects.  Keep decisions, slot assignment and
     count are bit-for-bit the untiled body's.  Returns the new count."""
     ntiles = wcap // wtile
-
-    # (a) dominated by a live window member, one wtile-wide sub-block at
-    # a time (same inertness argument as the untiled body: empty slots
-    # hold the sentinel coordinate and cannot dominate data below it)
     live = jnp.minimum(
         (jnp.minimum(count, wcap) + wtile - 1) // wtile, ntiles)
 
+    def tile(t):
+        return pl.ds(pl.multiple_of(t * wtile, wtile), wtile)
+
     def wbody(t, acc):
-        wt = pl.load(win_ref, (slice(None), pl.ds(t * wtile, wtile)))
-        le = jnp.ones((wtile, block_c), jnp.bool_)
-        lt = jnp.zeros((wtile, block_c), jnp.bool_)
-        for k in range(d):
-            wk = wt[k, :][:, None]   # (T, 1)
-            xk = x[k, :][None, :]    # (1, BC)
-            le = le & (wk <= xk)
-            lt = lt | (wk < xk)
-        return acc | jnp.any(le & lt, axis=0)
+        dom = _window_dominated(win_ref[:, tile(t)], x, d=d)
+        return acc | dom.astype(jnp.int32)
 
     domw = jax.lax.fori_loop(0, live, wbody,
-                             jnp.zeros((block_c,), jnp.bool_))
-
-    # (b) the in-block lower-triangular self-test (O(BC^2), tile-free)
-    keep = xm & ~domw & ~_self_test(x, d=d, block_c=block_c)
-
-    # (c) append: same scatter-free one-hot integer-bit copy as the
-    # untiled body, but built per touched tile — kept candidates land in
-    # slots [count, count+kept), so only tiles intersecting that range
-    # are visited (none when the window already overflowed: lo == hi)
-    ki = keep.astype(jnp.int32)
-    rid = jax.lax.broadcasted_iota(jnp.int32, (block_c, block_c), 0)
-    cid = jax.lax.broadcasted_iota(jnp.int32, (block_c, block_c), 1)
-    prefix = jnp.sum(ki[:, None] & (rid <= cid), axis=0)     # (BC,) incl c
-    pos = count + prefix - 1                                 # (BC,)
-    kept = jnp.sum(ki)
-    ibits = {4: jnp.int32, 2: jnp.int16, 1: jnp.int8}[
-        jnp.dtype(x.dtype).itemsize]
-    izero = jnp.zeros((), ibits)
+                             jnp.zeros((block_c,), jnp.int32)) > 0
+    pos, kept = _keep_slots(x, xm, domw, count, d=d, block_c=block_c)
+    # kept candidates land in slots [count, count+kept), so only tiles
+    # intersecting that range are visited (none once the window
+    # overflowed: lo == hi)
     lo = jnp.minimum(count // wtile, ntiles)
     hi = jnp.minimum((count + kept + wtile - 1) // wtile, ntiles)
 
     def abody(t, carry):
-        base = t * wtile
-        slot = base + jax.lax.broadcasted_iota(
-            jnp.int32, (block_c, wtile), 1)
-        onehot = keep[:, None] & (pos[:, None] == slot)      # (BC, T)
-        newrow = jnp.any(onehot, axis=0)                     # (T,)
-        cur = pl.load(win_ref, (slice(None), pl.ds(base, wtile)))
-        rows = []
-        for k in range(d):
-            xb = jax.lax.bitcast_convert_type(x[k, :], ibits)
-            vals = jnp.sum(jnp.where(onehot, xb[:, None], izero), axis=0)
-            row = jax.lax.bitcast_convert_type(vals, x.dtype)
-            rows.append(jnp.where(newrow, row, cur[k, :]))
-        pl.store(win_ref, (slice(None), pl.ds(base, wtile)),
-                 jnp.stack(rows))
-        curm = pl.load(wmask_ref, (slice(None), pl.ds(base, wtile)))
-        pl.store(wmask_ref, (slice(None), pl.ds(base, wtile)),
-                 curm | newrow[None, :].astype(jnp.int32))
+        win_ref[:, tile(t)] = _append(x, pos, t * wtile, wtile,
+                                      win_ref[:, tile(t)])
         return carry
 
     jax.lax.fori_loop(lo, hi, abody, jnp.int32(0))
     return count + kept
 
 
-def _sfs_sweep_kernel(cands_ref, mask_ref, win_ref, wmask_ref, count_ref,
+def _sfs_sweep_kernel(cands_ref, mask_ref, win_ref, count_ref, count_smem,
                       *, d: int, block_c: int, wcap: int, wtile: int,
                       sentinel):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        win_ref[...] = jnp.full_like(win_ref, sentinel)
-        wmask_ref[...] = jnp.zeros_like(wmask_ref)
-        count_ref[0, 0] = jnp.int32(0)
+        win_ref[...] = jnp.full(win_ref.shape, sentinel, win_ref.dtype)
+        count_smem[0] = jnp.int32(0)
 
     x = cands_ref[...]           # (D_PAD, BC)
     xm = mask_ref[0, :] > 0      # (BC,)
-    count = count_ref[0, 0]      # () int32
+    count = count_smem[0]        # () int32, scalar memory
 
     if wtile:  # window-tiled step: resident tests bounded at T x BC
-        count_ref[0, 0] = _tiled_block_step(
-            x, xm, count, win_ref, wmask_ref, d=d, block_c=block_c,
-            wcap=wcap, wtile=wtile)
-        return
-
-    w = win_ref[...]             # (D_PAD, W)
-
-    # (a) dominated by a live window member.  The whole resident window
-    # is tested at once with NO validity mask: empty slots hold the
-    # sentinel coordinate in every attribute and therefore cannot
-    # dominate data below the sentinel (same inertness argument as the
-    # jnp sweep — the caller controls all padding).
-    le = jnp.ones((wcap, block_c), jnp.bool_)
-    lt = jnp.zeros((wcap, block_c), jnp.bool_)
-    for k in range(d):  # unrolled: d is a static 2..8
-        wk = w[k, :][:, None]    # (W, 1)
-        xk = x[k, :][None, :]    # (1, BC)
-        le = le & (wk <= xk)
-        lt = lt | (wk < xk)
-    domw = jnp.any(le & lt, axis=0)  # (BC,)
-
-    # (b) the in-block lower-triangular self-test (shared helper)
-    keep = xm & ~domw & ~_self_test(x, d=d, block_c=block_c)  # (BC,)
-    rid = jax.lax.broadcasted_iota(jnp.int32, (block_c, block_c), 0)
-    cid = jax.lax.broadcasted_iota(jnp.int32, (block_c, block_c), 1)
-
-    # (c) append: slot of candidate c is count + |kept earlier in block|.
-    # The in-block prefix count is a (BC, BC) masked reduction (no cumsum
-    # primitive needed on the lane axis), and the scatter is a one-hot
-    # masked sum over the INTEGER BITS of the values — exactly one
-    # non-zero contributor per slot, and integer addition is exact, so
-    # the copy preserves every bit (including -0.0, which a float sum
-    # would flip to +0.0).  Keeps past the window capacity match no slot
-    # id and are dropped, mirroring the reference's `mode="drop"`
-    # scatter.
-    ki = keep.astype(jnp.int32)
-    prefix = jnp.sum(ki[:, None] & (rid <= cid), axis=0)     # (BC,) incl c
-    pos = count + prefix - 1                                 # (BC,)
-    slot = jax.lax.broadcasted_iota(jnp.int32, (block_c, wcap), 1)
-    onehot = keep[:, None] & (pos[:, None] == slot)          # (BC, W)
-    newrow = jnp.any(onehot, axis=0)                         # (W,)
-    ibits = {4: jnp.int32, 2: jnp.int16, 1: jnp.int8}[
-        jnp.dtype(x.dtype).itemsize]
-    izero = jnp.zeros((), ibits)
-    for k in range(d):
-        xb = jax.lax.bitcast_convert_type(x[k, :], ibits)    # (BC,)
-        vals = jnp.sum(jnp.where(onehot, xb[:, None], izero), axis=0)
-        row = jax.lax.bitcast_convert_type(vals, x.dtype)    # (W,)
-        win_ref[k, :] = jnp.where(newrow, row, w[k, :])
-    wmask_ref[0, :] = wmask_ref[0, :] | newrow.astype(jnp.int32)
-    count_ref[0, 0] = count + jnp.sum(ki)
+        new = _tiled_block_step(x, xm, count, win_ref, d=d,
+                                block_c=block_c, wcap=wcap, wtile=wtile)
+    else:      # the whole resident window tested at once
+        w = win_ref[...]         # (D_PAD, W)
+        domw = _window_dominated(w, x, d=d)
+        pos, kept = _keep_slots(x, xm, domw, count, d=d,
+                                block_c=block_c)
+        win_ref[...] = _append(x, pos, 0, wcap, w)
+        new = count + kept
+    count_smem[0] = new
+    count_ref[...] = jnp.full(count_ref.shape, new, jnp.int32)
 
 
 @functools.partial(
@@ -233,7 +220,7 @@ def sfs_sweep_pallas(
     sentinel: float,
     wtile: int = 0,
     interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fused SFS sweep over a batch of score-sorted partitions.
 
     Args:
@@ -253,10 +240,11 @@ def sfs_sweep_pallas(
       interpret: run the kernel body in interpret mode (CPU validation).
 
     Returns:
-      ``(window_t (P * D_PAD, wcap), wmask (P, wcap) int32,
-      count (P, 1) int32)`` — the packed per-partition skyline window in
-      the same transposed layout, its validity mask, and the total number
-      of kept (skyline) rows, which may exceed ``wcap`` under overflow.
+      ``(window_t (P * D_PAD, wcap), count (P,) int32)`` — the packed
+      per-partition skyline window in the same transposed layout and the
+      total number of kept (skyline) rows, which may exceed ``wcap``
+      under overflow.  The window is packed: its valid slots are exactly
+      ``[0, min(count, wcap))``.
     """
     pd_pad, n = cands_t.shape
     assert pd_pad % D_PAD == 0, pd_pad
@@ -266,51 +254,78 @@ def sfs_sweep_pallas(
     assert wtile == 0 or wcap % wtile == 0, (wcap, wtile)
     d = D_PAD  # attribute rows are padded/inert; unroll over all of them
 
-    grid = (p, n // block_c)
     kernel = functools.partial(_sfs_sweep_kernel, d=d, block_c=block_c,
                                wcap=wcap, wtile=wtile, sentinel=sentinel)
-    return pl.pallas_call(
+    vmem = sweep_vmem_bytes(block_c=block_c, wcap=wcap, wtile=wtile,
+                            itemsize=jnp.dtype(cands_t.dtype).itemsize)
+    win_t, count = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(p, n // block_c),
         in_specs=[
             pl.BlockSpec((D_PAD, block_c), lambda i, j: (i, j)),
-            pl.BlockSpec((1, block_c), lambda i, j: (i, j)),
+            # a squeezed partition dim keeps the block's last two dims
+            # (1, BC) legal for any P (the second-minor equals the array's)
+            pl.BlockSpec((pl.squeezed, 1, block_c), lambda i, j: (i, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((D_PAD, wcap), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, wcap), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((pl.squeezed, 1, LANE), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((pd_pad, wcap), cands_t.dtype),
-            jax.ShapeDtypeStruct((p, wcap), jnp.int32),
-            jax.ShapeDtypeStruct((p, 1), jnp.int32),
+            jax.ShapeDtypeStruct((p, 1, LANE), jnp.int32),
         ],
+        # the running count is a scalar: it lives in scalar memory and is
+        # broadcast into one lane row of the count output per step
+        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes(vmem)),
+        name="sfs_sweep",
         interpret=interpret,
-    )(cands_t, mask)
+    )(cands_t, mask.reshape(p, 1, n))
+    return win_t, count[:, 0, 0]
 
 
 def sweep_vmem_bytes(*, block_c: int, wcap: int, wtile: int = 0,
                      itemsize: int = 4) -> int:
-    """Static per-grid-step VMEM footprint estimate for the sweep kernel.
+    """Upper bound on the sweep kernel's scoped VMEM, in bytes.
 
-    Counts the pipelined block I/O plus the materialized intermediates
-    of one ``(partition, candidate-block)`` step: the window tests, the
-    ``(BC, BC)`` intra-block self-tests, and the append routing one-hot.
-    Untiled (``wtile=0``) the tests/one-hot span the whole window —
-    ``(W, BC)`` / ``(BC, W)`` — the W x BC law; with ``wtile=T`` they
-    span one T-column sub-block at a time, so the bound drops to T x BC
-    (only the d_pad x W window buffer itself still scales with W).
-    Booleans are counted at one byte; `broadcasted_iota` comparisons are
-    treated as fused into their consumers (Mosaic lowers them lazily),
-    so this is the data-carrying-tensor bound, in bytes. The static
-    verifier (`repro.analysis`) gates every compiled configuration
-    against it, which is what lets capacity/block changes land without
-    re-deriving the tiling by hand."""
+    Terms, per ``(partition, candidate-block)`` grid step: the resident
+    ``(D_PAD, W)`` window block (counted twice, as if double-buffered),
+    the pipelined candidate/mask/count blocks, and the intermediates of
+    one window test over ``weff`` slots (the whole window untiled, one
+    ``wtile`` tile otherwise): each attribute's ``(weff, 1)`` window
+    column occupies a full 128-lane row of 32-bit words, and the
+    ``(weff, BC)`` / ``(BC, BC)`` test masks are held at 32 bits.  The
+    column term is what makes an untiled wide window expensive: Mosaic
+    needs 16.2 MiB at W=4096, BC=256 untiled and 1.4 MiB at the same
+    window with a 512-slot tile (compiled for a described v5e).  The
+    Mosaic compile tests (tests/test_tpu_compile.py) hold the kernel to
+    this bound, and the kernel requests it as its VMEM limit when it
+    exceeds the compiler's default."""
     weff = wcap if wtile <= 0 else min(wtile, wcap)
-    io = (D_PAD * block_c + D_PAD * wcap) * itemsize \
-        + (block_c + wcap + 1) * 4              # mask/wmask/count (int32)
-    win_tests = 2 * weff * block_c              # le, lt (bool)
-    self_tests = 2 * block_c * block_c          # le_s, lt_s (bool)
-    append = block_c * weff                     # onehot (bool)
-    return io + win_tests + self_tests + append
+    window = 2 * D_PAD * wcap * itemsize
+    blocks = 2 * (D_PAD * block_c * itemsize + 8 * block_c * 4) \
+        + 2 * 8 * LANE * 4                       # cands, mask, count
+    columns = D_PAD * weff * LANE * 4
+    tests = 4 * weff * block_c + 8 * block_c * block_c
+    return window + blocks + columns + tests
+
+
+VMEM_DEFAULT = 16 * 2 ** 20   # Mosaic's default scoped VMEM limit (v5e)
+VMEM_MAX = 100 * 2 ** 20      # below the 128 MiB of one v5e TensorCore
+
+
+def vmem_limit_bytes(estimate: int) -> int | None:
+    """The scoped-VMEM limit a sweep of this estimate asks Mosaic for:
+    the compiler default when the estimate fits under it, else the
+    estimate itself.  A geometry past `VMEM_MAX` is a caller error —
+    the TPU path tiles wide windows (`repro.kernels.sfs.ops`), so only
+    an explicitly pinned huge untiled window gets here."""
+    if estimate <= VMEM_DEFAULT:
+        return None
+    if estimate > VMEM_MAX:
+        raise ValueError(
+            f"sfs sweep needs ~{estimate} B of VMEM (> {VMEM_MAX}); "
+            f"tile the window (wtile)")
+    return int(estimate)

@@ -58,7 +58,7 @@ from repro.kernels.backend import KernelSpec, resolve_spec
 from repro.kernels.sfs import kernel as _kernel
 from repro.kernels.sfs import ref as _ref
 
-__all__ = ["sfs_sweep"]
+__all__ = ["sfs_sweep", "tpu_geometry", "traced_geometries", "TPU_WTILE"]
 
 
 def _sweep_one_jnp(pts_s, mask_s, *, block: int, wcap: int, sentinel,
@@ -208,23 +208,91 @@ def _pack_transposed(pts_s, d_pad):
     return cands_t.reshape(p * d_pad, npad)
 
 
+# window tile of the compiled TPU sweep for windows wider than one tile
+TPU_WTILE = 512
+
+
+def tpu_geometry(block: int, npad: int, wcap: int, wtile: int,
+                 ) -> tuple[int, int]:
+    """``(candidate block, window tile)`` the compiled TPU sweep runs for
+    a requested geometry — derived from the shapes alone.
+
+    Mosaic wants the last dim of every block to be a multiple of the
+    128-lane tile or the whole array, and dynamic window slices to
+    start on a lane multiple.  So a candidate block that is neither is
+    rounded up to the lane tile (the caller pads the batch with inert
+    sentinel rows), and a window wider than `TPU_WTILE` is always
+    tested in lane-aligned tiles: untiled, the ``(W, 1)`` window columns
+    alone take ~4 KiB of VMEM per slot (`sweep_vmem_bytes`), and every
+    step would test all W slots rather than the live ones.  A requested
+    tile is kept when it is a lane-aligned divisor of the window.  A
+    window that is not a lane multiple (blocks below 128 rows, i.e.
+    tiny inputs) stays untiled.  Candidate block and tile are pure
+    schedule: every geometry is bit-identical."""
+    lane = _kernel.LANE
+    bc = block if block % lane == 0 or block == npad else _ceil_to(block,
+                                                                   lane)
+    if wcap <= TPU_WTILE or wcap % lane:
+        return bc, 0
+    t = wtile if 0 < wtile < wcap and wtile % lane == 0 else TPU_WTILE
+    while wcap % t:
+        t -= lane
+    return bc, t
+
+
+def _ceil_to(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+# trace-time record of the compiled sweep geometries (one entry per
+# trace, like `repro.core.parallel.trace_count`), so a run can report
+# which tiling its programs actually compiled
+_GEOMETRIES: list[dict] = []
+
+
+def traced_geometries() -> list[dict]:
+    """Every Pallas sweep geometry traced so far, oldest first."""
+    return list(_GEOMETRIES)
+
+
 def _sweep_pallas(pts_s, mask_s, *, block: int, wcap: int, wtile: int,
                   sentinel, interpret: bool):
     """Pack the sorted batch into the TPU kernel's transposed layout,
-    run the one-grid sweep, and unpack."""
+    run the one-grid sweep, and unpack.  Compiled (not interpret), the
+    geometry is `tpu_geometry`'s; interpret mode runs the requested one
+    so CPU tests can exercise any tile."""
     p, npad, d = pts_s.shape
     if d > _kernel.D_PAD:
         raise ValueError(
             f"d={d} > {_kernel.D_PAD} not supported by the Pallas sweep; "
             f"use impl='jnp'")
+    if not interpret:
+        block, wtile = tpu_geometry(block, npad, wcap, wtile)
+        if npad % block:  # pad with inert rows to whole candidate blocks
+            extra = _ceil_to(npad, block) - npad
+            pts_s = jnp.pad(pts_s, ((0, 0), (0, extra), (0, 0)),
+                            constant_values=sentinel)
+            mask_s = jnp.pad(mask_s, ((0, 0), (0, extra)))
+    _GEOMETRIES.append(dict(
+        p=p, n=pts_s.shape[1], d=d, block=block, wcap=wcap, wtile=wtile,
+        interpret=interpret,
+        vmem_limit=_kernel.vmem_limit_bytes(_kernel.sweep_vmem_bytes(
+            block_c=block, wcap=wcap, wtile=wtile,
+            itemsize=jnp.dtype(pts_s.dtype).itemsize))))
     cands_t = _pack_transposed(pts_s, _kernel.D_PAD)
     mask2d = mask_s.astype(jnp.int32)
-    win_t, wmask, count = _kernel.sfs_sweep_pallas(
+    win_t, count = _kernel.sfs_sweep_pallas(
         cands_t, mask2d, block_c=block, wcap=wcap, wtile=wtile,
         sentinel=float(sentinel), interpret=interpret)
     window = jnp.swapaxes(
         win_t.reshape(p, _kernel.D_PAD, wcap)[:, :d, :], 1, 2)
-    return window, wmask > 0, count[:, 0]
+    return window, _packed_mask(count, wcap), count
+
+
+def _packed_mask(count, wcap: int):
+    """(P, wcap) validity of a packed window: the sweep fills slots in
+    keep order from 0, so exactly the first min(count, wcap) are valid."""
+    return jnp.arange(wcap, dtype=jnp.int32)[None, :] < count[:, None]
 
 
 def _sweep_gpu(pts_s, mask_s, *, block: int, wcap: int, wtile: int,
@@ -236,11 +304,11 @@ def _sweep_gpu(pts_s, mask_s, *, block: int, wcap: int, wtile: int,
     d_pad = -(-max(d, 1) // _kernel.D_PAD) * _kernel.D_PAD
     cands_t = _pack_transposed(pts_s, d_pad)
     mask2d = mask_s.astype(jnp.int32)
-    win_t, wmask, count = _gpu.sfs_sweep_pallas_gpu(
+    win_t, count = _gpu.sfs_sweep_pallas_gpu(
         cands_t, mask2d, block_c=block, wcap=wcap, wtile=wtile,
         sentinel=float(sentinel), interpret=interpret)
     window = jnp.swapaxes(win_t.reshape(p, d_pad, wcap)[:, :d, :], 1, 2)
-    return window, wmask > 0, count[:, 0]
+    return window, _packed_mask(count, wcap), count
 
 
 def _normalize_wtile(wtile: int, wcap: int, block: int) -> int:

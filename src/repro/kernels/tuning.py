@@ -44,7 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["TuneEntry", "TuningTable", "calibrate_kernels",
-           "default_table", "set_default_table", "tuning_key"]
+           "check_platform", "default_table", "set_default_table",
+           "tuning_key"]
 
 ENV_VAR = "REPRO_KERNEL_TUNING"
 
@@ -67,8 +68,9 @@ class TuneEntry:
 @dataclasses.dataclass
 class TuningTable:
     """Tuned (block, wtile) per ``family/d=D/dtype=NAME`` key, plus the
-    topology it was measured on (informational — a table is valid
-    anywhere, it is just only *optimal* on the topology that made it)."""
+    topology it was measured on.  Any geometry is bit-identical, but a
+    table is applied only on the platform that timed it
+    (`check_platform`)."""
     entries: dict[str, TuneEntry] = dataclasses.field(default_factory=dict)
     topology: dict[str, Any] = dataclasses.field(default_factory=dict)
 
@@ -110,28 +112,48 @@ _DEFAULT: TuningTable | None = None
 _DEFAULT_LOADED = False
 
 
+def check_platform(table: TuningTable, source: str = "table") -> None:
+    """Refuse a table timed on another backend: a geometry measured on
+    the CPU says nothing about the TPU (and vice versa), so it must
+    never be applied there.  A table with no recorded platform cannot
+    show where it was measured and is refused too."""
+    want = jax.default_backend()
+    got = table.topology.get("platform")
+    if got != want:
+        raise ValueError(
+            f"kernel tuning {source} was measured on platform {got!r}, "
+            f"not the running {want!r}; recalibrate on this platform "
+            f"(repro.kernels.tuning.calibrate_kernels)")
+
+
 def set_default_table(table: TuningTable | None) -> None:
     """Install ``table`` as the process default (None clears it and
     re-arms the env-var load)."""
     global _DEFAULT, _DEFAULT_LOADED
+    if table is not None:
+        check_platform(table)
     _DEFAULT = table
     _DEFAULT_LOADED = table is not None
 
 
 def default_table() -> TuningTable | None:
     """The process-default tuning table: whatever `set_default_table`
-    installed, else a one-time lazy load from ``$REPRO_KERNEL_TUNING``
-    (missing/invalid paths degrade to None — an untuned process must
-    run, not crash)."""
+    installed, else a one-time lazy load from ``$REPRO_KERNEL_TUNING``.
+    A named table that cannot be read, or that was measured on another
+    platform, raises: a run that asked for a tuned geometry must not
+    silently run another one."""
     global _DEFAULT, _DEFAULT_LOADED
     if not _DEFAULT_LOADED:
-        _DEFAULT_LOADED = True
         path = os.environ.get(ENV_VAR)
         if path:
             try:
-                _DEFAULT = TuningTable.load(path)
-            except (OSError, ValueError, KeyError, TypeError):
-                _DEFAULT = None
+                table = TuningTable.load(path)
+            except (OSError, ValueError, KeyError, TypeError) as e:
+                raise ValueError(f"${ENV_VAR}={path!r} cannot be read as "
+                                 f"a tuning table: {e}") from e
+            check_platform(table, repr(path))
+            _DEFAULT = table
+        _DEFAULT_LOADED = True
     return _DEFAULT
 
 

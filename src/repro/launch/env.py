@@ -16,7 +16,11 @@ file per host:
 Also plumbed here: ``REPRO_KERNEL_TUNING`` — the path to a persisted
 kernel-tuning table (`repro.kernels.tuning`), so a calibrated
 (block, wtile) table travels to every child process of a launch the
-same way the allocator settings do.
+same way the allocator settings do — and JAX's persistent compilation
+cache (`use_compile_cache`): where ``JAX_COMPILATION_CACHE_DIR`` is set,
+JAX reads it and nothing here overrides it; otherwise the cache lives
+at a fixed path inside the checkout (``.jax_cache``), so every run from
+the same checkout finds what earlier runs compiled.
 
 LD_PRELOAD only takes effect at process start, so `apply_env` cannot
 retro-tune the *current* process's allocator — use the ``-m`` exec form
@@ -29,7 +33,15 @@ from __future__ import annotations
 import os
 import sys
 
-__all__ = ["TCMALLOC_PATHS", "build_env", "apply_env", "main"]
+__all__ = ["TCMALLOC_PATHS", "CACHE_ENV", "build_env", "apply_env",
+           "use_compile_cache", "main"]
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.jax_cache: fixed, so the cache key (which includes the
+# path) is the same on every run from this checkout
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 # well-known tcmalloc locations (Debian/Ubuntu multiarch first — the
 # path the TPU-host launch scripts preload)
@@ -89,6 +101,19 @@ def apply_env(*, devices: int | None = None, tuning: str | None = None,
             os.environ[key] = val
             applied[key] = val
     return applied
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory: ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX reads the
+    variable itself; no directory is set in code), else the fixed
+    in-checkout `REPO_CACHE_DIR`."""
+    import jax
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        path = REPO_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def main(argv: list[str] | None = None) -> None:

@@ -368,9 +368,11 @@ class SkylineEngine:
             resolve_spec(impl)
             return dataclasses.replace(cfg, impl=impl)
         if (cfg.impl == "auto" and cfg.wtile == 0 and d is not None):
-            from repro.kernels.tuning import default_table, tuning_key
+            from repro.kernels.tuning import (check_platform,
+                                              default_table, tuning_key)
             table = self.kernel_tuning or default_table()
             if table is not None:
+                check_platform(table)
                 entry = table.entries.get(
                     tuning_key("sweep", d, dtype or jnp.float32))
                 if entry is not None and entry.bitwise_ok:

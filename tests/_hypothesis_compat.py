@@ -3,16 +3,16 @@
 `hypothesis` is a declared test dependency (pyproject.toml) but not a
 hard one: when it is missing, the property tests must *skip at run time*
 while every plain pytest test in the same module still collects and
-runs. Test modules import `given`, `settings`, `st` from here instead of
-from hypothesis directly; with hypothesis absent the stand-in `given`
-produces a test whose body is `pytest.importorskip("hypothesis")`, so it
-reports as skipped with the canonical reason.
+runs. Test modules import `given`, `example`, `settings`, `st` from here
+instead of from hypothesis directly; with hypothesis absent the stand-in
+`given` produces a test whose body is `pytest.importorskip("hypothesis")`,
+so it reports as skipped with the canonical reason.
 """
 
 from __future__ import annotations
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:  # degrade: property tests skip, others run
     import pytest
@@ -36,6 +36,8 @@ except ImportError:  # degrade: property tests skip, others run
             return fn
         return deco
 
+    example = settings
+
     class _AnyStrategy:
         """st.<anything>(...) placeholder; only ever passed to the no-op
         `given` above, never executed."""
@@ -45,4 +47,4 @@ except ImportError:  # degrade: property tests skip, others run
 
     st = _AnyStrategy()
 
-__all__ = ["given", "settings", "st", "HAVE_HYPOTHESIS"]
+__all__ = ["given", "example", "settings", "st", "HAVE_HYPOTHESIS"]

@@ -211,9 +211,13 @@ def test_vmem_estimate_tracks_tiling():
     assert small["sweep"] < big["sweep"]
     assert small["dominance"] < big["dominance"]
     assert big["window_rows"] == 16384
-    # the documented kernel regime (W=4096, BC=512) sits under 16 MiB
+    # the serving regime (W=4096, BC=512) busts 16 MiB untiled — Mosaic
+    # already needs 16.2 MiB at BC=256 (tests/test_tpu_compile.py) —
+    # and sits under it at the TPU path's default window tile
     from repro.analysis.verifier import DEFAULT_VMEM_CAP
-    doc = vmem_estimate(512, 4096)
+    from repro.kernels.sfs.ops import TPU_WTILE
+    assert vmem_estimate(512, 4096)["sweep"] > DEFAULT_VMEM_CAP
+    doc = vmem_estimate(512, 4096, wtile=TPU_WTILE)
     assert doc["sweep"] < DEFAULT_VMEM_CAP
     assert doc["dominance"] < DEFAULT_VMEM_CAP
 

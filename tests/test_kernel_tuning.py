@@ -4,6 +4,7 @@ rules (explicit impl / pinned wtile always win)."""
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -22,11 +23,12 @@ def _clean_default_table():
     set_default_table(None)
 
 
-def _table(block=128, wtile=128, ok=True):
+def _table(block=128, wtile=128, ok=True, platform=None):
     return TuningTable(entries={
         tuning_key("sweep", 4, jnp.float32):
             TuneEntry(block=block, wtile=wtile, time_us=1.0, impl="jnp",
-                      bitwise_ok=ok)})
+                      bitwise_ok=ok)},
+        topology={"platform": platform or jax.default_backend()})
 
 
 def test_table_json_roundtrip(tmp_path):
@@ -45,10 +47,35 @@ def test_env_var_loads_default_table(tmp_path, monkeypatch):
     set_default_table(None)  # re-arm the lazy load
     tab = default_table()
     assert tab is not None and tab.lookup("sweep", 4, "float32").block == 64
-    # a broken path degrades to None, never raises
-    monkeypatch.setenv("REPRO_KERNEL_TUNING", str(tmp_path / "nope.json"))
+    # no variable, no table
+    monkeypatch.delenv("REPRO_KERNEL_TUNING")
     set_default_table(None)
     assert default_table() is None
+
+
+def test_unreadable_table_path_raises(tmp_path, monkeypatch):
+    """A named table that cannot be read fails loudly: the run asked for
+    a tuned geometry and must not silently run another one."""
+    monkeypatch.setenv("REPRO_KERNEL_TUNING", str(tmp_path / "nope.json"))
+    with pytest.raises(ValueError, match="cannot be read"):
+        default_table()
+    bad = tmp_path / "bad.json"
+    bad.write_text("{not json")
+    monkeypatch.setenv("REPRO_KERNEL_TUNING", str(bad))
+    with pytest.raises(ValueError, match="cannot be read"):
+        default_table()
+
+
+def test_table_from_another_platform_refused(tmp_path, monkeypatch):
+    """A geometry timed on one backend is never applied on another (a
+    CPU-timed table says nothing about the TPU)."""
+    other = "tpu" if jax.default_backend() != "tpu" else "cpu"
+    path = _table(platform=other).save(str(tmp_path / "t.json"))
+    monkeypatch.setenv("REPRO_KERNEL_TUNING", path)
+    with pytest.raises(ValueError, match="measured on platform"):
+        default_table()
+    with pytest.raises(ValueError, match="measured on platform"):
+        set_default_table(TuningTable.load(path))
 
 
 def test_calibrate_kernels_quick():

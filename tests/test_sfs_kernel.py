@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from _hypothesis_compat import example, given, settings, st
 
 from repro.core.sfs import block_sfs, local_skyline_batch, naive_skyline_mask
 from repro.kernels.backend import KernelSpec, resolve_spec
@@ -326,6 +326,10 @@ def test_wide_d_on_gpu_sweep():
 @given(st.integers(1, 2), st.integers(1, 120), st.integers(2, 5),
        st.sampled_from([16, 32]), st.integers(0, 96),
        st.integers(0, 2 ** 31 - 1))
+# minimal failures hypothesis once found (a one-row batch, a tile wider
+# than the window, and the untiled request)
+@example(p=1, n=1, d=2, blk=32, wtile=56, seed=56)
+@example(p=1, n=1, d=2, blk=16, wtile=0, seed=0)
 def test_hypothesis_tiled_parity(p, n, d, blk, wtile, seed):
     """Property: for ANY requested wtile (divisor or not, 0, oversized)
     every tiled impl is bit-for-bit the per-pair reference, including

@@ -81,6 +81,39 @@ def test_compact():
     np.testing.assert_array_equal(got, np.asarray(pts)[::2])
 
 
+@pytest.mark.parametrize("n,d", [(1, 2), (50, 2), (300, 4), (257, 7)])
+def test_canonical_order_is_lexsort(n, d):
+    """The chained single-key sorts give exactly the multi-key lexsort
+    (score first, then coordinates, then input position) — with score
+    ties, duplicate rows, -0.0 and invalid (+inf score) rows."""
+    from repro.core.dominance import canonical_order, monotone_score
+    rng = np.random.default_rng(n * 10 + d)
+    pts = (rng.integers(0, 3, (n, d)) / 2).astype(np.float32)
+    pts[rng.random(n) < 0.1, 0] = -0.0
+    mask = rng.random(n) > 0.2
+    score = np.asarray(monotone_score(jnp.asarray(pts), jnp.asarray(mask)))
+    want = np.lexsort(tuple(pts[:, j] for j in reversed(range(d)))
+                      + (score,))
+    got = np.asarray(jax.jit(canonical_order)(jnp.asarray(pts),
+                                              jnp.asarray(mask)))
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("n,cap,frac", [(1, 1, 0.5), (7, 3, 0.5),
+                                        (100, 100, 0.0), (100, 40, 1.0),
+                                        (1000, 257, 0.3), (64, 128, 0.7)])
+def test_compact_order_is_stable_partition(n, cap, frac):
+    """The sort-free compaction order is exactly a stable argsort of
+    ~mask, truncated — what every caller's side columns rely on."""
+    from repro.core.sfs import compact_order
+    rng = np.random.default_rng(n + cap)
+    mask = rng.random(n) < frac
+    want = np.argsort(~mask, kind="stable")[:cap]
+    got = np.asarray(jax.jit(compact_order, static_argnums=1)(
+        jnp.asarray(mask), cap))
+    np.testing.assert_array_equal(got, want)
+
+
 @settings(max_examples=25, deadline=None)
 @given(st.integers(1, 200), st.integers(2, 7), st.integers(0, 3),
        st.integers(0, 2 ** 31 - 1))
