@@ -316,7 +316,7 @@ def serve(engine, seed):
         with ServeLoop(engine) as loop:
             tickets = [loop.submit(SkylineRequest(data=x)) for x in data]
             for tk in tickets:
-                tk.wait(timeout=900)
+                tk.wait(timeout=300)
             stats = dict(loop.stats)
         dt = time.perf_counter() - t
         if stats["shed"] or any(tk.status != "ok" for tk in tickets):
