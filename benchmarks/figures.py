@@ -696,8 +696,7 @@ def serving_latency(bursts=12, width=4, n=1024, d=4, mean_gap_ms=12.0,
         emit(f"serving_latency/depth={depth}/req={requests},n={n}",
              p99 * 1e6,
              f"p50_ms={p50 * 1e3:.2f};p99_ms={p99 * 1e3:.2f};"
-             f"waves={loop.stats['waves']};"
-             f"overlap_s={loop.stats['stage_overlap_s']:.3f}")
+             f"waves={loop.stats['waves']}")
     emit(f"serving_latency/dispatch_ahead_gain/req={requests},n={n}",
          (p99s[1] - p99s[2]) * 1e6,
          f"p99_off_over_on={p99s[1] / p99s[2]:.2f}x")

@@ -141,26 +141,34 @@ def _fit_rows(points: jnp.ndarray, mask: jnp.ndarray, rows: int):
 def _chunk_skyline(pts, mask, key, *, cfg: SkyConfig, mesh, axis_name: str):
     """SKY(chunk) via partition -> local -> merge, optionally shard_mapped
     over a 1-D ``workers`` mesh (no host sync; see repro.core.parallel)."""
-    buckets, meta, stats = par.partition_stage(pts, mask, cfg, key)
-    p = meta["p"]
+    # the partition scope spans routing and the hand-off to the workers
+    # (the local key's derivation included), in the order they trace
+    with jax.named_scope(par.PARTITION_SCOPE):
+        buckets, meta, stats = par.partition_stage(pts, mask, cfg, key)
+        p = meta["p"]
+        if mesh is None:
+            local_key = jax.random.fold_in(key, 1)
+        else:
+            nworkers = mesh.shape[axis_name]
+            if p % nworkers != 0:
+                raise ValueError(
+                    f"p={p} not divisible by {nworkers} workers")
+            # Hand the routed buckets to the workers axis *inside* the
+            # same program — a sharding constraint, not a host transfer.
+            spec = NamedSharding(mesh, P(axis_name))
+            bufs = jax.lax.with_sharding_constraint(buckets.points, spec)
+            bmask = jax.lax.with_sharding_constraint(buckets.mask, spec)
+            part_idx = jax.lax.with_sharding_constraint(meta["part_idx"],
+                                                        spec)
+            cells = jax.lax.with_sharding_constraint(meta["cells"], spec)
+            local_key = jax.random.fold_in(key, 1)
 
     if mesh is None:
         final, s2 = par._local_merge(
-            buckets.points, buckets.mask, jax.random.fold_in(key, 1),
+            buckets.points, buckets.mask, local_key,
             meta["part_idx"], meta["cells"], cfg=cfg, meta=meta,
             gather=lambda x: x)
     else:
-        nworkers = mesh.shape[axis_name]
-        if p % nworkers != 0:
-            raise ValueError(f"p={p} not divisible by {nworkers} workers")
-        # Hand the routed buckets to the workers axis *inside* the same
-        # program — a sharding constraint, not a host transfer.
-        spec = NamedSharding(mesh, P(axis_name))
-        bufs = jax.lax.with_sharding_constraint(buckets.points, spec)
-        bmask = jax.lax.with_sharding_constraint(buckets.mask, spec)
-        part_idx = jax.lax.with_sharding_constraint(meta["part_idx"], spec)
-        cells = jax.lax.with_sharding_constraint(meta["cells"], spec)
-        local_key = jax.random.fold_in(key, 1)
 
         def body(bufs, bmask, part_idx, cells, local_key):
             gather = lambda x: jax.lax.all_gather(
@@ -170,7 +178,8 @@ def _chunk_skyline(pts, mask, key, *, cfg: SkyConfig, mesh, axis_name: str):
                                          gather=gather, axis_name=axis_name,
                                          axis_size=nworkers)
             # gather per-partition stats, keep scalars replicated
-            s2["local_sizes"] = gather(s2["local_sizes"])
+            with jax.named_scope(par.MERGE_SCOPE):
+                s2["local_sizes"] = gather(s2["local_sizes"])
             return final, s2
 
         final, s2 = shard_map(
@@ -182,8 +191,9 @@ def _chunk_skyline(pts, mask, key, *, cfg: SkyConfig, mesh, axis_name: str):
             check_vma=False)(bufs, bmask, part_idx, cells, local_key)
 
     stats.update(s2)
-    overflow = (buckets.overflow | stats.get("local_overflow", False)
-                | final.overflow)
+    with jax.named_scope(par.MERGE_SCOPE):
+        overflow = (buckets.overflow | stats.get("local_overflow", False)
+                    | final.overflow)
     final = SkyBuffer(final.points, final.mask, final.count, overflow)
     return final, stats
 
@@ -210,23 +220,24 @@ def _chunk_skyline_batch(pts, mask, keys, *, cfg: SkyConfig, mesh,
         buckets, _, stats = par.partition_stage(pts_i, mask_i, cfg, key_i)
         return buckets, stats
 
-    buckets, stats = jax.vmap(part_one)(pts, mask, keys)
-    # per-partition metadata is query-independent — build it once, and
-    # shard it over the workers axis only (no queries dimension)
-    cells = (par._grid_cells(p, m, d) if cfg.strategy == "grid"
-             else jnp.zeros((p, d), jnp.int32))
-    part_idx = jnp.arange(p, dtype=jnp.int32)
-    meta = {"p": p, "m": m, "cells": cells, "part_idx": part_idx}
+    with jax.named_scope(par.PARTITION_SCOPE):
+        buckets, stats = jax.vmap(part_one)(pts, mask, keys)
+        # per-partition metadata is query-independent — build it once,
+        # and shard it over the workers axis only (no queries dimension)
+        cells = (par._grid_cells(p, m, d) if cfg.strategy == "grid"
+                 else jnp.zeros((p, d), jnp.int32))
+        part_idx = jnp.arange(p, dtype=jnp.int32)
+        meta = {"p": p, "m": m, "cells": cells, "part_idx": part_idx}
 
-    spec_qw = NamedSharding(mesh, P(q_axis, w_axis))
-    spec_w = NamedSharding(mesh, P(w_axis))
-    bufs = jax.lax.with_sharding_constraint(buckets.points, spec_qw)
-    bmask = jax.lax.with_sharding_constraint(buckets.mask, spec_qw)
-    part_idx = jax.lax.with_sharding_constraint(part_idx, spec_w)
-    cells = jax.lax.with_sharding_constraint(cells, spec_w)
-    local_keys = jax.lax.with_sharding_constraint(
-        jax.vmap(lambda k: jax.random.fold_in(k, 1))(keys),
-        NamedSharding(mesh, P(q_axis)))
+        spec_qw = NamedSharding(mesh, P(q_axis, w_axis))
+        spec_w = NamedSharding(mesh, P(w_axis))
+        bufs = jax.lax.with_sharding_constraint(buckets.points, spec_qw)
+        bmask = jax.lax.with_sharding_constraint(buckets.mask, spec_qw)
+        part_idx = jax.lax.with_sharding_constraint(part_idx, spec_w)
+        cells = jax.lax.with_sharding_constraint(cells, spec_w)
+        local_keys = jax.lax.with_sharding_constraint(
+            jax.vmap(lambda k: jax.random.fold_in(k, 1))(keys),
+            NamedSharding(mesh, P(q_axis)))
 
     def body(bufs, bmask, part_idx, cells, local_keys):
         gather = lambda x: jax.lax.all_gather(x, w_axis, axis=0, tiled=True)
@@ -235,7 +246,8 @@ def _chunk_skyline_batch(pts, mask, keys, *, cfg: SkyConfig, mesh,
             final, s2 = par._local_merge(b, bm, k, part_idx, cells, cfg=cfg,
                                          meta=meta, gather=gather,
                                          axis_name=w_axis, axis_size=nw)
-            s2["local_sizes"] = gather(s2["local_sizes"])
+            with jax.named_scope(par.MERGE_SCOPE):
+                s2["local_sizes"] = gather(s2["local_sizes"])
             return final, s2
 
         return jax.vmap(one)(bufs, bmask, local_keys)
@@ -249,7 +261,9 @@ def _chunk_skyline_batch(pts, mask, keys, *, cfg: SkyConfig, mesh,
         check_vma=False)(bufs, bmask, part_idx, cells, local_keys)
 
     stats.update(s2)
-    overflow = (buckets.overflow | s2["local_overflow"] | final.overflow)
+    with jax.named_scope(par.MERGE_SCOPE):
+        overflow = (buckets.overflow | s2["local_overflow"]
+                    | final.overflow)
     final = SkyBuffer(final.points, final.mask, final.count, overflow)
     return final, stats
 

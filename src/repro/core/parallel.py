@@ -65,7 +65,20 @@ from repro.kernels.backend import resolve_spec
 __all__ = ["SkyConfig", "parallel_skyline", "fused_skyline_fn",
            "fused_skyline_batch_fn", "effective_parts", "partition_stage",
            "local_stage", "merge_stage", "merge_rounds", "resolve_merge",
-           "trace_count"]
+           "trace_count", "STAGE_SCOPES", "DISPATCH_SPAN"]
+
+# Names of the pipeline's stages in the compiled program. Each stage runs
+# under a `jax.named_scope` of its name, so every op the program traces
+# carries exactly one of them in its HLO ``op_name`` metadata (and nothing
+# else of the compiled program changes). A profiler trace attributes
+# device time to stages by these names.
+PARTITION_SCOPE = "sky.partition"    # part ids, routing, hand-off to workers
+REP_FILTER_SCOPE = "sky.rep_filter"  # representative filtering (paper §4.1)
+LOCAL_SCOPE = "sky.local"            # per-partition skylines
+MERGE_SCOPE = "sky.merge"            # union, final sweep, canonical order
+STAGE_SCOPES = (PARTITION_SCOPE, REP_FILTER_SCOPE, LOCAL_SCOPE, MERGE_SCOPE)
+# host span (`jax.profiler.TraceAnnotation`) around each one-shot dispatch
+DISPATCH_SPAN = "sky.dispatch"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -181,28 +194,31 @@ def local_stage(bufs, bmask, cfg: SkyConfig, *, key=None, gather=None):
     stats: dict[str, Any] = {}
 
     if cfg.rep_filter:
-        dom_impl = resolve_spec(cfg.impl).dominance
-        reps, rmask = _select_local_reps(bufs, bmask, cfg, key)
-        pool = gather(reps).reshape(-1, d)
-        pmask = gather(rmask).reshape(-1)
-        # drop dominated representatives before sharing (paper §4.1)
-        pmask = pmask & ~jax.vmap(
-            lambda t: jnp.any((jnp.all(pool <= t, -1) &
-                               jnp.any(pool < t, -1)) & pmask))(pool)
-        before = jnp.sum(bmask)
-        bmask = jax.vmap(lambda b, m: filtering.filter_by_representatives(
-            b, m, pool, pmask, impl=dom_impl))(bufs, bmask)
-        stats["rep_filter_dropped"] = before - jnp.sum(bmask)
+        with jax.named_scope(REP_FILTER_SCOPE):
+            dom_impl = resolve_spec(cfg.impl).dominance
+            reps, rmask = _select_local_reps(bufs, bmask, cfg, key)
+            pool = gather(reps).reshape(-1, d)
+            pmask = gather(rmask).reshape(-1)
+            # drop dominated representatives before sharing (paper §4.1)
+            pmask = pmask & ~jax.vmap(
+                lambda t: jnp.any((jnp.all(pool <= t, -1) &
+                                   jnp.any(pool < t, -1)) & pmask))(pool)
+            before = jnp.sum(bmask)
+            bmask = jax.vmap(
+                lambda b, m: filtering.filter_by_representatives(
+                    b, m, pool, pmask, impl=dom_impl))(bufs, bmask)
+            stats["rep_filter_dropped"] = before - jnp.sum(bmask)
 
     # Phase 1 proper: the whole partition batch through ONE fused-sweep
     # dispatch (window test + self-test + append fused; no per-pair
     # dominance launches — see repro.kernels.sfs).
-    local_cap = cfg.local_capacity or cap
-    sky = local_skyline_batch(bufs, bmask, capacity=local_cap,
-                              block=cfg.block, impl=cfg.impl,
-                              wtile=cfg.wtile)
-    stats["local_sizes"] = sky.count
-    stats["local_overflow"] = jnp.any(sky.overflow)
+    with jax.named_scope(LOCAL_SCOPE):
+        local_cap = cfg.local_capacity or cap
+        sky = local_skyline_batch(bufs, bmask, capacity=local_cap,
+                                  block=cfg.block, impl=cfg.impl,
+                                  wtile=cfg.wtile)
+        stats["local_sizes"] = sky.count
+        stats["local_overflow"] = jnp.any(sky.overflow)
     return sky, stats
 
 
@@ -514,9 +530,10 @@ def _local_merge(bufs, bmask, key, part_idx, cells, *, cfg: SkyConfig,
     under shard_map — the tree merge permutes over it; without an axis
     the merge runs the flat schedule (same bits)."""
     sky, s2 = local_stage(bufs, bmask, cfg, key=key, gather=gather)
-    final, s3 = merge_stage(sky, meta, cfg, part_idx_local=part_idx,
-                            cells_local=cells, gather=gather,
-                            axis_name=axis_name, axis_size=axis_size)
+    with jax.named_scope(MERGE_SCOPE):
+        final, s3 = merge_stage(sky, meta, cfg, part_idx_local=part_idx,
+                                cells_local=cells, gather=gather,
+                                axis_name=axis_name, axis_size=axis_size)
     return final, dict(s2, **s3)
 
 
@@ -618,11 +635,14 @@ def parallel_skyline(pts: jnp.ndarray, mask: jnp.ndarray | None = None, *,
     `axis_name` and executed under shard_map; p must be a multiple of the
     mesh axis size. partition -> local -> merge execute as a single
     device-resident program: no intermediate device_put, and the stats
-    pytree is made of device arrays (host sync only when read).
+    pytree is made of device arrays (host sync only when read). The host
+    work of the call (defaults, program lookup, enqueue) runs under the
+    profiler span `DISPATCH_SPAN`.
     """
-    n = pts.shape[0]
-    if mask is None:
-        mask = jnp.ones((n,), jnp.bool_)
-    if key is None:
-        key = jax.random.PRNGKey(0)
-    return fused_skyline_fn(cfg, mesh, axis_name)(pts, mask, key)
+    with jax.profiler.TraceAnnotation(DISPATCH_SPAN):
+        n = pts.shape[0]
+        if mask is None:
+            mask = jnp.ones((n,), jnp.bool_)
+        if key is None:
+            key = jax.random.PRNGKey(0)
+        return fused_skyline_fn(cfg, mesh, axis_name)(pts, mask, key)

@@ -102,14 +102,12 @@ class _Wave:
     buffers whose readiness marks its completion, the wave-time model
     buckets it updates, and its clock."""
 
-    __slots__ = ("tickets", "markers", "keys", "staged_at",
-                 "dispatched_at")
+    __slots__ = ("tickets", "markers", "keys", "dispatched_at")
 
-    def __init__(self, tickets, markers, keys, staged_at, dispatched_at):
+    def __init__(self, tickets, markers, keys, dispatched_at):
         self.tickets = tickets
         self.markers = markers
         self.keys = keys
-        self.staged_at = staged_at
         self.dispatched_at = dispatched_at
 
 
@@ -175,8 +173,7 @@ class ServeLoop:
                     continue
                 self._tuning_floor[(d, dt)] = entry.time_us * 1e-6
         self.stats = {"completed": 0, "shed": 0, "degraded": 0,
-                      "waves": 0, "coalesced_feeds": 0,
-                      "stage_overlap_s": 0.0}
+                      "waves": 0, "coalesced_feeds": 0}
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -374,7 +371,6 @@ class ServeLoop:
         dispatch per group), same-bucket stream feeds fuse via
         `_wave_feed`. Returns the in-flight record whose markers the
         completion thread blocks on."""
-        staged_at = self._clock()
         markers: list = []
         queries = [t for t in batch if t.kind == "query"]
         feeds = [t for t in batch if t.kind == "feed"]
@@ -406,7 +402,7 @@ class ServeLoop:
                         self._watch[id(t.stream)] = t.stream
         self.stats["waves"] += 1
         keys = sorted({self._model_key(t) for t in batch})
-        return _Wave(batch, markers, keys, staged_at, self._clock())
+        return _Wave(batch, markers, keys, self._clock())
 
     # -- completion thread -------------------------------------------------
 
@@ -437,7 +433,5 @@ class ServeLoop:
                         wave_time if prev is None else
                         self._alpha * wave_time
                         + (1 - self._alpha) * prev)
-                self.stats["stage_overlap_s"] += max(
-                    0.0, wave.dispatched_at - wave.staged_at)
                 self._inflight -= 1
                 self._work.notify_all()
