@@ -12,6 +12,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.dominance import SENTINEL
 
@@ -113,27 +114,32 @@ def bucketize(pts: jnp.ndarray, mask: jnp.ndarray, ids: jnp.ndarray, p: int,
               capacity: int) -> Buckets:
     """Route tuples to (p, capacity) buckets with validity masks.
 
-    Stable sort by partition id (invalid rows sort to a virtual partition
-    p), positions within a partition via searchsorted on the sorted ids,
-    rows beyond capacity are dropped and flagged as overflow.
+    One stable sort by partition id (invalid rows sort to a virtual
+    partition p) carries the row indices along; a binary search for each
+    partition's first and one-past-last position in that order gives its
+    start and count, and each bucket slot (j, s) then reads the row at
+    sorted position start[j] + s by one gather. Rows keep their input order
+    within a partition; rows beyond capacity are dropped and flagged as
+    overflow. `ids` must lie in [0, p).
     """
     n, d = pts.shape
     ids_eff = jnp.where(mask, ids, p).astype(jnp.int32)
-    order = jnp.argsort(ids_eff)
-    ids_s = ids_eff[order]
-    pts_s = pts[order]
-    mask_s = mask[order]
-    pos = jnp.arange(n, dtype=jnp.int32) - jnp.searchsorted(
-        ids_s, ids_s, side="left").astype(jnp.int32)
-    ok = mask_s & (ids_s < p) & (pos < capacity)
-    dest = jnp.where(ok, ids_s * capacity + pos, p * capacity)
-    flat = jnp.full((p * capacity, d), SENTINEL, pts.dtype)
-    flat = flat.at[dest].set(pts_s, mode="drop")
-    fmask = jnp.zeros((p * capacity,), jnp.bool_)
-    fmask = fmask.at[dest].set(True, mode="drop")
-    counts = jax.ops.segment_sum(mask.astype(jnp.int32),
-                                 jnp.where(mask, ids, p).astype(jnp.int32),
-                                 num_segments=p + 1)[:p]
+    ids_s, order = lax.sort((ids_eff, jnp.arange(n, dtype=jnp.int32)),
+                            num_keys=1, is_stable=True)
+    # (p, 2) bounds, so that a mesh sharding the buckets by partition finds
+    # each partition's start and end on the same worker
+    part = jnp.arange(p, dtype=jnp.int32)[:, None]
+    bounds = jnp.searchsorted(
+        ids_s, part + jnp.arange(2, dtype=jnp.int32)).astype(jnp.int32)
+    starts, counts = bounds[:, 0], bounds[:, 1] - bounds[:, 0]
     overflow = jnp.any(counts > capacity)
-    return Buckets(flat.reshape(p, capacity, d),
-                   fmask.reshape(p, capacity), counts, overflow)
+    # each bucket's row indices are one contiguous run of `order`
+    order_pad = jnp.concatenate([order, jnp.zeros((capacity,), jnp.int32)])
+    rows = jax.vmap(lambda s: lax.dynamic_slice(order_pad, (s,), (capacity,)))(
+        starts)
+    valid = jnp.arange(capacity, dtype=jnp.int32)[None, :] < counts[:, None]
+    # an empty table still gives the gather one row, which no slot keeps
+    table = pts if n else jnp.zeros((1, d), pts.dtype)
+    points = jnp.where(valid[..., None], table[rows],
+                       jnp.asarray(SENTINEL, pts.dtype))
+    return Buckets(points, valid, counts, overflow)

@@ -1,6 +1,8 @@
 """Partitioning strategies: Proposition 1 (partition-local-merge identity),
 bucketize integrity, balance properties, grid/angular index validity."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +11,7 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core import naive_skyline_mask
 from repro.core.datagen import generate
+from repro.core.dominance import SENTINEL
 from repro.core.parallel import SkyConfig, effective_parts, parallel_skyline
 from repro.core.partition import (angular_part_ids, bucketize,
                                   grid_cell_coords, grid_part_ids,
@@ -58,6 +61,101 @@ def test_bucketize_overflow_detection():
     b = bucketize(pts, jnp.ones(50, bool), ids, 4, capacity=10)
     assert bool(b.overflow)
     assert int(b.counts[0]) == 50
+
+
+def _np_bucketize(pts, mask, ids, p, capacity):
+    """Plain routing: each partition's valid rows in input order, the first
+    `capacity` of them kept, `counts` the valid rows per id."""
+    n, d = pts.shape
+    points = np.full((p, capacity, d), SENTINEL, pts.dtype)
+    bmask = np.zeros((p, capacity), bool)
+    counts = np.zeros((p,), np.int32)
+    for j in range(p):
+        rows = np.flatnonzero(mask & (ids == j))
+        counts[j] = rows.size
+        keep = rows[:capacity]
+        points[j, :keep.size] = pts[keep]
+        bmask[j, :keep.size] = True
+    return points, bmask, counts, np.bool_(np.any(counts > capacity))
+
+
+def _routing_case(name, rng):
+    n, d, p, cap = 120, 3, 5, 120
+    pts = rng.random((n, d)).astype(np.float32)
+    mask = rng.random(n) > 0.25
+    ids = rng.integers(0, p, n).astype(np.int32)
+    if name == "p1":
+        p, ids = 1, np.zeros(n, np.int32)
+    elif name == "empty":
+        pts, mask, ids = pts[:0], mask[:0], ids[:0]
+    elif name == "all_invalid":
+        mask = np.zeros(n, bool)
+    elif name == "overflow":
+        cap = 7
+    elif name == "last_partition":
+        ids = np.full(n, p - 1, np.int32)
+    elif name == "inf_values":
+        pts[rng.random((n, d)) < 0.2] = np.inf
+    return pts, mask, ids, p, cap
+
+
+def _assert_same_buckets(got, want):
+    for g, w in zip(got, want):
+        g = np.asarray(g)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+
+
+@pytest.mark.parametrize("case", ["p1", "empty", "all_invalid", "overflow",
+                                  "last_partition", "inf_values", "vmap"])
+def test_bucketize_matches_numpy_routing(case):
+    rng = np.random.default_rng(7)
+    if case == "vmap":
+        # the batch programs route each query's table under jax.vmap
+        cases = [_routing_case(case, rng) for _ in range(3)]
+        pts, mask, ids = (np.stack([c[k] for c in cases]) for k in range(3))
+        p, cap = cases[0][3:]
+        route = jax.vmap(lambda x, m, i: bucketize(x, m, i, p, cap))
+        want = [np.stack(w) for w in zip(*(_np_bucketize(*c) for c in cases))]
+    else:
+        pts, mask, ids, p, cap = _routing_case(case, rng)
+        route = functools.partial(bucketize, p=p, capacity=cap)
+        want = _np_bucketize(pts, mask, ids, p, cap)
+    got = route(jnp.asarray(pts), jnp.asarray(mask), jnp.asarray(ids))
+    _assert_same_buckets(got, want)
+    assert bool(np.any(got.overflow)) == (case == "overflow")
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _all_eqns(sub)
+
+
+def test_bucketize_has_no_scatter_and_no_table_wide_loop():
+    """Routing reads the buckets by gathers: no scatter, and its one loop
+    (the binary search for each partition's two bounds) gathers 2p entries
+    per level, not one per row."""
+    n, p, cap = 4096, 5, 1024
+    args = (jnp.zeros((n, 3), jnp.float32), jnp.ones((n,), bool),
+            jnp.zeros((n,), jnp.int32))
+    f = functools.partial(bucketize, p=p, capacity=cap)
+    assert "scatter" not in jax.jit(f).lower(*args).as_text()
+    eqns = list(_all_eqns(jax.make_jaxpr(f)(*args).jaxpr))
+    assert not [e for e in eqns if "scatter" in e.primitive.name]
+    loops = [e for e in eqns if e.primitive.name in ("scan", "while")]
+    assert len(loops) <= 1
+    for loop in loops:
+        assert loop.params.get("length", 0) <= int(np.ceil(np.log2(n + 1)))
+        body = loop.params.get("jaxpr", loop.params.get("body_jaxpr"))
+        gathers = [e for e in _all_eqns(body.jaxpr)
+                   if e.primitive.name == "gather"]
+        assert gathers
+        assert all(np.prod(g.invars[1].aval.shape) == 2 * p for g in gathers)
 
 
 def test_random_and_sliced_balance():
