@@ -35,10 +35,10 @@ def dominance_matrix(refs: jnp.ndarray, cands: jnp.ndarray) -> jnp.ndarray:
 
 
 def dominated_mask(cands, refs, ref_mask=None, *, lower_tri=False,
-                   impl="auto"):
+                   impl="auto", name="dominated_mask"):
     """Blocked kernel entry point (see kernels/dominance/ops.py)."""
     return _dominated_mask(cands, refs, ref_mask, lower_tri=lower_tri,
-                           impl=impl)
+                           impl=impl, name=name)
 
 
 def region_volume(pts: jnp.ndarray) -> jnp.ndarray:

@@ -378,10 +378,19 @@ def _tree_merge(sky: SkyBuffer, cfg: SkyConfig, *, part_idx_local,
         msk = jnp.pad(msk, (0, cap_u - take))
         pparts = jnp.pad(pparts, (0, cap_u - take))
         pcells = jnp.pad(pcells, ((0, cap_u - take), (0, 0)))
+    # the flat merge's count of potential-dominator rows, from the
+    # partitions' ids, cells and sliced maxima and this worker's rows
+    all_gather = lambda x: jax.lax.all_gather(x, axis_name, axis=0,
+                                              tiled=True)
+    pd_refs = jax.lax.psum(noseq.pd_refs(
+        cfg.strategy, all_gather(part_idx_local), all_gather(cells_local),
+        all_gather(noseq.slice_max(sky.points, sky.mask, cfg.sliced_dim)),
+        parts, cells, flat[:, cfg.sliced_dim], fmask), axis_name)
     # self-filter within the worker (covers the same-shard pairs the
     # flat merge tests through the full gathered reference set)
     msk = noseq.relative_rows_mask(pts, msk, pparts, pcells,
-                                   strategy=cfg.strategy, block=cfg.block)
+                                   strategy=cfg.strategy, block=cfg.block,
+                                   dim=cfg.sliced_dim)
 
     for r in range(merge_rounds(w)):
         s = 1 << r
@@ -396,7 +405,8 @@ def _tree_merge(sky: SkyBuffer, cfg: SkyConfig, *, part_idx_local,
             [pcells, rcv[:, d + 2:].astype(jnp.int32)])
         cmsk = noseq.relative_rows_mask(cpts, cmsk, cparts, ccells,
                                         strategy=cfg.strategy,
-                                        block=cfg.block)
+                                        block=cfg.block,
+                                        dim=cfg.sliced_dim)
         order = compact_order(cmsk, cap_u)
         pts, msk = cpts[order], cmsk[order]
         pparts, pcells = cparts[order], ccells[order]
@@ -407,7 +417,7 @@ def _tree_merge(sky: SkyBuffer, cfg: SkyConfig, *, part_idx_local,
     final = compact(pts[order], msk[order], cfg.capacity)
     final = SkyBuffer(final.points, final.mask, final.count,
                       final.overflow | overflow)
-    return final, {"union_size": union_size}
+    return final, {"union_size": union_size, "noseq_pd_refs": pd_refs}
 
 
 def merge_stage(sky: SkyBuffer, meta, cfg: SkyConfig, *,
@@ -479,16 +489,19 @@ def merge_stage(sky: SkyBuffer, meta, cfg: SkyConfig, *,
     ref_parts = ref_parts[order]
     ref_cells = ref_cells[order]
 
+    ref_vals = refs[:, cfg.sliced_dim]
     dom_impl = resolve_spec(cfg.impl).dominance
 
-    def filter_one(u_i, m_i, own_part, own_cell):
+    def filter_one(u_i, m_i, own_part, own_cell, own_max):
         pd = noseq.pd_row_mask(cfg.strategy, own_part, ref_parts,
-                               own_cell, ref_cells)
-        return noseq.relative_skyline_mask(u_i, m_i, refs, refmask, pd,
+                               own_cell, ref_cells, own_max, ref_vals)
+        keep = noseq.relative_skyline_mask(u_i, m_i, refs, refmask, pd,
                                            impl=dom_impl)
+        return keep, jnp.sum(refmask & pd, dtype=jnp.int32)
 
-    final_mask_local = jax.vmap(filter_one)(
-        sky.points, sky.mask, part_idx_local, cells_local)
+    final_mask_local, pd_refs = jax.vmap(filter_one)(
+        sky.points, sky.mask, part_idx_local, cells_local,
+        noseq.slice_max(sky.points, sky.mask, cfg.sliced_dim))
     # assemble a single replicated result buffer, in canonical order
     # (total: score, then lexicographic coordinates) before compaction,
     # so the merge output is independent of the partition layout — the
@@ -500,7 +513,10 @@ def merge_stage(sky: SkyBuffer, meta, cfg: SkyConfig, *,
     final = compact(all_pts[order], all_mask[order], cfg.capacity)
     final = SkyBuffer(final.points, final.mask, final.count,
                       final.overflow | u_compact.overflow)
-    return final, {"union_size": union_size}
+    # valid rows each partition was tested against, summed: the useful
+    # part of the p x C_loc x |u| padded test
+    return final, {"union_size": union_size,
+                   "noseq_pd_refs": jnp.sum(gather(pd_refs))}
 
 
 # --------------------------------------------------------------------------
@@ -540,7 +556,8 @@ def _local_merge(bufs, bmask, key, part_idx, cells, *, cfg: SkyConfig,
 def _body_stat_keys(cfg: SkyConfig) -> tuple[str, ...]:
     """Stats emitted by `_local_merge` (shard_map out_specs need them)."""
     return ("local_sizes", "local_overflow", "union_size",
-            *(("rep_filter_dropped",) if cfg.rep_filter else ()))
+            *(("rep_filter_dropped",) if cfg.rep_filter else ()),
+            *(("noseq_pd_refs",) if cfg.noseq else ()))
 
 
 def _fused(pts, mask, key, *, cfg: SkyConfig, mesh, axis_name: str):

@@ -83,7 +83,7 @@ def _dominance_kernel(cands_ref, refs_ref, mask_ref, out_ref, *, d: int,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("lower_tri", "block_c", "block_r", "interpret"))
+    static_argnames=("lower_tri", "block_c", "block_r", "interpret", "name"))
 def dominated_mask_pallas(
     cands_t: jnp.ndarray,
     refs_t: jnp.ndarray,
@@ -93,6 +93,7 @@ def dominated_mask_pallas(
     block_c: int = 512,
     block_r: int = 512,
     interpret: bool = False,
+    name: str = "dominated_mask",
 ) -> jnp.ndarray:
     """Blocked dominance-test kernel.
 
@@ -103,6 +104,8 @@ def dominated_mask_pallas(
       lower_tri: self-join mode — ref j may only dominate cand i if j < i
         (global indices). Requires cands_t and refs_t to be the same array.
       interpret: run the kernel body in interpret mode (CPU validation).
+      name: the kernel's name in the compiled program, which a device
+        trace shows as the op's name.
 
     Returns:
       (1, C) int32 — nonzero where the candidate is dominated.
@@ -126,7 +129,7 @@ def dominated_mask_pallas(
         ],
         out_specs=pl.BlockSpec((1, block_c), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, c), jnp.int32),
-        name="dominated_mask",
+        name=name,
         interpret=interpret,
     )(cands_t, refs_t, ref_mask)
 

@@ -78,7 +78,7 @@ def _dominated_mask_jnp(cands, refs, ref_mask, lower_tri):
 
 
 def _dominated_mask_pallas(cands, refs, ref_mask, lower_tri, block_c,
-                           block_r, interpret, gpu=False):
+                           block_r, interpret, gpu=False, *, name):
     c, d = cands.shape
     r = refs.shape[0]
     cp = _ceil_to(max(c, 1), block_c)
@@ -102,13 +102,13 @@ def _dominated_mask_pallas(cands, refs, ref_mask, lower_tri, block_c,
     else:
         out = _kernel.dominated_mask_pallas(
             cands_t, refs_t, mask2d, lower_tri=lower_tri, block_c=block_c,
-            block_r=block_r, interpret=interpret)
+            block_r=block_r, interpret=interpret, name=name)
     return out[0, :c] > 0
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("lower_tri", "impl", "block_c", "block_r"))
+    static_argnames=("lower_tri", "impl", "block_c", "block_r", "name"))
 def dominated_mask(
     cands: jnp.ndarray,
     refs: jnp.ndarray,
@@ -118,10 +118,13 @@ def dominated_mask(
     impl: str = "auto",
     block_c: int = 512,
     block_r: int = 512,
+    name: str = "dominated_mask",
 ) -> jnp.ndarray:
     """(C,) bool: for each candidate, is it dominated by a valid ref?
 
-    See ref.dominated_mask_ref for exact semantics.
+    See ref.dominated_mask_ref for exact semantics.  ``name`` names the
+    TPU kernel (the op a device trace shows); the jnp path has no kernel
+    to name.
     """
     if cands.ndim != 2 or refs.ndim != 2:
         raise ValueError("cands/refs must be (N, d)")
@@ -145,5 +148,5 @@ def dominated_mask(
         return _dominated_mask_pallas(
             cands, refs, ref_mask, lower_tri, block_c, block_r,
             interpret=impl in ("interpret", "gpu_interpret"),
-            gpu=impl in ("gpu", "gpu_interpret"))
+            gpu=impl in ("gpu", "gpu_interpret"), name=name)
     raise ValueError(f"unknown impl {impl!r}")

@@ -1,11 +1,16 @@
 """Representative Filtering (paper §4.1), Grid Filtering (§3.2) and NoSeq
 (§4.2, Proposition 2) — soundness and exactness properties."""
 
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from _hypothesis_compat import example, given, settings, st
 
 from repro.core import naive_skyline_mask
 from repro.core.datagen import generate
@@ -95,6 +100,9 @@ def test_proposition2_noseq_identity(strategy, dist):
 @given(st.sampled_from(["random", "sliced", "grid", "angular"]),
        st.sampled_from([None, "sorted", "region"]),
        st.booleans(), st.integers(0, 2 ** 31 - 1))
+# equal sliced values straddle a slice boundary: a later slice holds the
+# only dominator of a row
+@example(strategy="sliced", rep=None, noseq=True, seed=1)
 def test_hypothesis_full_pipeline(strategy, rep, noseq, seed):
     """Prop 1 + Prop 2 + rep-filtering composed, random quantized data."""
     rng = np.random.default_rng(seed)
@@ -108,3 +116,46 @@ def test_hypothesis_full_pipeline(strategy, rep, noseq, seed):
     assert not bool(buf.overflow)
     got = set(map(tuple, np.asarray(buf.points)[np.asarray(buf.mask)]))
     assert got == _sky_set(pts)
+
+
+# Slices of 2 rows (n = 8, p = 4).  x and y share the sliced value 0.5;
+# the sort breaks the tie by row index, so x (row 3) ends slice 1 and y
+# (row 4) opens slice 2, and y is x's only dominator.
+TIE_ROWS = [[0.1, 0.95], [0.2, 0.9], [0.3, 0.85], [0.5, 0.6],
+            [0.5, 0.4], [0.6, 0.3], [0.7, 0.2], [0.8, 0.1]]
+TIE_CASE = """
+import dataclasses
+import jax, jax.numpy as jnp, numpy as np
+from repro.core.parallel import SkyConfig, parallel_skyline
+from repro.launch.mesh import make_worker_mesh
+pts = jnp.asarray({rows}, jnp.float32)
+want = {{tuple(r) for r in np.asarray(pts)[[0, 1, 2, 4, 5, 6, 7]]}}
+base = SkyConfig(strategy="sliced", p=4, capacity=16, block=8,
+                 bucket_factor=2.0, noseq=True)
+meshes = [make_worker_mesh({devices})] + ([None] if {devices} == 1 else [])
+for mesh in meshes:
+    for merge in ("flat", "tree"):
+        cfg = dataclasses.replace(base, merge=merge)
+        buf, st = parallel_skyline(pts, cfg=cfg, mesh=mesh)
+        got = {{tuple(r) for r in np.asarray(buf.points)[
+            np.asarray(buf.mask)]}}
+        assert got == want and int(buf.count) == 7, (merge, mesh, got)
+        assert not bool(buf.overflow)
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_noseq_sliced_dominator_in_a_later_slice(devices):
+    """NoSeq over SLICED finds a dominator that a tie put in the next
+    slice, under the flat and the tree merge, on one forced host device
+    (with and without a workers mesh) and on a four-worker mesh."""
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(root, "src"),
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    code = TIE_CASE.format(rows=TIE_ROWS, devices=devices)
+    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                       capture_output=True, text=True, timeout=600, env=env)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OK" in r.stdout

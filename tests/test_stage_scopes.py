@@ -6,8 +6,11 @@ on a workers mesh each collective falls under the stage that issues it:
 the partition shuffle under ``sky.partition``, the flat or tree merge's
 under ``sky.merge``.  Ops the compiler makes up itself carry no source
 frame in their metadata (a rewritten reduce-window, a broadcast the
-partitioner splits off) and are left out.  The host work of a one-shot
-call runs under the profiler span ``sky.dispatch``.
+partitioner splits off) and are left out.  Each Pallas kernel runs under
+the stage of its work: NoSeq's phase-2 test (``noseq_mask``) under
+``sky.merge``, apart from the representative filter's ``dominated_mask``.
+The host work of a one-shot call runs under the profiler span
+``sky.dispatch``.
 """
 
 import glob
@@ -20,6 +23,7 @@ import textwrap
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.extend import core as jcore
 
 from repro.core import parallel
 from repro.core.parallel import SkyConfig, fused_skyline_fn
@@ -112,19 +116,56 @@ def check_stages(text: str) -> dict[str, dict[str, int]]:
     return colls
 
 
+def pallas_kernels(jaxpr, stack=()):
+    """[(kernel name, stages around it)] of every `pallas_call` of a
+    jaxpr and of the jaxprs inside it."""
+    out = []
+    for eqn in jaxpr.eqns:
+        here = stack + tuple(str(eqn.source_info.name_stack).split("/"))
+        if eqn.primitive.name == "pallas_call":
+            out.append((eqn.params["name"],
+                        tuple(p for p in here if p.startswith("sky."))))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    out += pallas_kernels(sub, here)
+    return out
+
+
+def program_kernels(cfg: SkyConfig, n: int, d: int) -> dict[str, set]:
+    """kernel name -> the stages it runs under, in the one-shot program."""
+    jaxpr = jax.make_jaxpr(fused_skyline_fn(cfg))(
+        jax.ShapeDtypeStruct((n, d), jnp.float32),
+        jax.ShapeDtypeStruct((n,), jnp.bool_), jax.random.PRNGKey(0))
+    out: dict[str, set] = {}
+    for name, stages in pallas_kernels(jaxpr.jaxpr):
+        out.setdefault(name, set()).add(stages)
+    return out
+
+
 def test_stage_names():
     assert parallel.STAGE_SCOPES == ("sky.partition", "sky.rep_filter",
                                      "sky.local", "sky.merge")
     assert parallel.DISPATCH_SPAN == "sky.dispatch"
 
 
-def test_one_device_program_ops_carry_one_stage():
+@pytest.mark.parametrize("noseq", [False, True])
+def test_one_device_program_ops_carry_one_stage(noseq):
     pts = jax.ShapeDtypeStruct((N, D), jnp.float32)
     mask = jax.ShapeDtypeStruct((N,), jnp.bool_)
-    text = fused_skyline_fn(SkyConfig(**CFG)).lower(
+    text = fused_skyline_fn(SkyConfig(noseq=noseq, **CFG)).lower(
         pts, mask, jax.random.PRNGKey(0)).compile().as_text()
     colls = check_stages(text)
     assert all(not c for c in colls.values())
+    kernels = program_kernels(
+        SkyConfig(noseq=noseq, impl="interpret", **CFG), N, D)
+    merge_sweep = set() if noseq else {("sky.merge",)}
+    assert kernels == {
+        "dominated_mask": {("sky.rep_filter",)},
+        "sfs_sweep": {("sky.local",)} | merge_sweep,
+        **({"noseq_mask": {("sky.merge",)}} if noseq else {})}
 
 
 FOUR = r'''
@@ -137,20 +178,23 @@ mesh = make_worker_mesh(4)
 sh = NamedSharding(mesh, P("workers"))
 pts = jax.ShapeDtypeStruct(({n}, {d}), jnp.float32, sharding=sh)
 mask = jax.ShapeDtypeStruct(({n},), jnp.bool_, sharding=sh)
-cfg = SkyConfig(merge={merge!r}, **{cfg!r})
+cfg = SkyConfig(merge={merge!r}, noseq={noseq!r}, **{cfg!r})
 text = fused_skyline_fn(cfg, mesh).lower(
     pts, mask, jax.random.PRNGKey(0)).compile().as_text()
 open({out!r}, "w").write(text)
 '''
 
 
+@pytest.mark.parametrize("noseq", [False, True])
 @pytest.mark.parametrize("merge", ["flat", "tree"])
-def test_four_worker_collectives_fall_under_their_stage(tmp_path, merge):
+def test_four_worker_collectives_fall_under_their_stage(tmp_path, merge,
+                                                        noseq):
     out = str(tmp_path / "hlo.txt")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.path.join(ROOT, "src"))
-    code = FOUR.format(n=N, d=D, merge=merge, cfg=CFG, out=out)
+    code = FOUR.format(n=N, d=D, merge=merge, noseq=noseq, cfg=CFG,
+                       out=out)
     p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        env=env, capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]

@@ -10,6 +10,7 @@ per kernel.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,6 +97,21 @@ def test_dominance_compiles(one_chip, lower_tri):
         one_chip, ((D_PAD, 4096), jnp.float32), ((D_PAD, 4096), jnp.float32),
         ((1, 4096), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_noseq_test_compiles_under_its_name(one_chip):
+    """NoSeq's phase 2 at the `ant5` cell's geometry: each of 8 local
+    skylines (8,192 rows) against the 65,536-row union, vmapped, under
+    the kernel name a device trace shows (`noseq_mask`)."""
+    from repro.kernels.dominance.ops import dominated_mask
+    text = _compile(
+        jax.vmap(lambda c, r, m: dominated_mask(c, r, m, impl="pallas",
+                                                name="noseq_mask"),
+                 in_axes=(0, None, 0)),
+        one_chip, ((8, 8192, 5), jnp.float32), ((65536, 5), jnp.float32),
+        ((8, 65536), jnp.bool_))
+    assert re.search(r"%noseq_mask[.\d]* = .*tpu_custom_call", text)
+    assert not re.search(r"%dominated_mask[.\d]* = ", text)
 
 
 @pytest.mark.parametrize("block,npad,wcap,wtile,want", [
