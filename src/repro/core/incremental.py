@@ -180,6 +180,8 @@ def _chunk_skyline(pts, mask, key, *, cfg: SkyConfig, mesh, axis_name: str):
             # gather per-partition stats, keep scalars replicated
             with jax.named_scope(par.MERGE_SCOPE):
                 s2["local_sizes"] = gather(s2["local_sizes"])
+                s2["sweep_blocks"] = jax.lax.psum(s2["sweep_blocks"],
+                                                  axis_name)
             return final, s2
 
         final, s2 = shard_map(
@@ -248,6 +250,8 @@ def _chunk_skyline_batch(pts, mask, keys, *, cfg: SkyConfig, mesh,
                                          axis_name=w_axis, axis_size=nw)
             with jax.named_scope(par.MERGE_SCOPE):
                 s2["local_sizes"] = gather(s2["local_sizes"])
+                s2["sweep_blocks"] = jax.lax.psum(s2["sweep_blocks"],
+                                                  w_axis)
             return final, s2
 
         return jax.vmap(one)(bufs, bmask, local_keys)

@@ -219,6 +219,9 @@ def local_stage(bufs, bmask, cfg: SkyConfig, *, key=None, gather=None):
                                   wtile=cfg.wtile)
         stats["local_sizes"] = sky.count
         stats["local_overflow"] = jnp.any(sky.overflow)
+        # candidate blocks the sweep runs (valid rows sort first), against
+        # p x npad / block padded
+        stats["sweep_blocks"] = jnp.sum(-(-jnp.sum(bmask, -1) // cfg.block))
     return sky, stats
 
 
@@ -328,6 +331,8 @@ def _tree_merge(sky: SkyBuffer, cfg: SkyConfig, *, part_idx_local,
                       min(flat.shape[0], max(cfg.capacity, 1)))
         buf = block_sfs(own.points, own.mask, capacity=cfg.capacity,
                         block=cfg.block, impl=cfg.impl, wtile=cfg.wtile)
+        sweep_blocks = jax.lax.psum(-(-jnp.sum(own.mask) // cfg.block),
+                                    axis_name)
         pts, msk = buf.points, buf.mask
 
         dom_impl = resolve_spec(cfg.impl).dominance
@@ -361,7 +366,8 @@ def _tree_merge(sky: SkyBuffer, cfg: SkyConfig, *, part_idx_local,
         order = canonical_order(pts, msk)
         final = SkyBuffer(pts[order], msk[order],
                           jnp.sum(msk).astype(jnp.int32), overflow)
-        return final, {"union_size": union_size}
+        return final, {"union_size": union_size,
+                       "merge_sweep_blocks": sweep_blocks}
 
     # NoSeq: rows keep their origin partition (and grid cell) so the
     # potential-dominator mask is evaluated per row pair in-round
@@ -471,7 +477,9 @@ def merge_stage(sky: SkyBuffer, meta, cfg: SkyConfig, *,
         overflow = final.overflow | u_compact.overflow
         final = SkyBuffer(final.points[order], final.mask[order],
                           final.count, overflow)
-        return final, {"union_size": union_size}
+        return final, {"union_size": union_size,
+                       "merge_sweep_blocks":
+                           -(-jnp.sum(u_compact.mask) // cfg.block)}
 
     refs = u_pts.reshape(-1, d)
     refmask = u_mask.reshape(-1)
@@ -555,9 +563,9 @@ def _local_merge(bufs, bmask, key, part_idx, cells, *, cfg: SkyConfig,
 
 def _body_stat_keys(cfg: SkyConfig) -> tuple[str, ...]:
     """Stats emitted by `_local_merge` (shard_map out_specs need them)."""
-    return ("local_sizes", "local_overflow", "union_size",
+    return ("local_sizes", "local_overflow", "union_size", "sweep_blocks",
             *(("rep_filter_dropped",) if cfg.rep_filter else ()),
-            *(("noseq_pd_refs",) if cfg.noseq else ()))
+            *(("noseq_pd_refs",) if cfg.noseq else ("merge_sweep_blocks",)))
 
 
 def _fused(pts, mask, key, *, cfg: SkyConfig, mesh, axis_name: str):

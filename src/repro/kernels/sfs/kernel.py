@@ -23,6 +23,14 @@ each kept candidate to its window slot with a masked integer-bit sum
 so the copy preserves every bit, -0.0 included), which keeps the kernel
 free of dynamic-index stores.
 
+Each partition's scan stops at its last block that holds a valid row:
+the per-partition block bound is a scalar-prefetched operand, a step
+past it does nothing, and its candidate index map revisits the last
+swept block so the pipeline fetches nothing new.  Valid rows sort
+first, so after the representative filter and in a compacted merge
+buffer most of a padded bucket is such a tail; a block with no valid
+row keeps nothing, so skipping it changes no output bit.
+
 Semantics are bit-for-bit those of the per-pair reference
 (:func:`repro.kernels.sfs.ref.sfs_sweep_perpair`, the seed ``block_sfs``
 body): identical keep decisions, identical slot assignment (first ``W``
@@ -180,9 +188,10 @@ def _tiled_block_step(x, xm, count, win_ref, *, d: int, block_c: int,
     return count + kept
 
 
-def _sfs_sweep_kernel(cands_ref, mask_ref, win_ref, count_ref, count_smem,
-                      *, d: int, block_c: int, wcap: int, wtile: int,
-                      sentinel):
+def _sfs_sweep_kernel(nblk_ref, cands_ref, mask_ref, win_ref, count_ref,
+                      count_smem, *, d: int, block_c: int, wcap: int,
+                      wtile: int, sentinel):
+    i = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -190,22 +199,26 @@ def _sfs_sweep_kernel(cands_ref, mask_ref, win_ref, count_ref, count_smem,
         win_ref[...] = jnp.full(win_ref.shape, sentinel, win_ref.dtype)
         count_smem[0] = jnp.int32(0)
 
-    x = cands_ref[...]           # (D_PAD, BC)
-    xm = mask_ref[0, :] > 0      # (BC,)
-    count = count_smem[0]        # () int32, scalar memory
+    # blocks past the partition's last valid row keep nothing: skip them
+    @pl.when(j < nblk_ref[i])
+    def _step():
+        x = cands_ref[...]           # (D_PAD, BC)
+        xm = mask_ref[0, :] > 0      # (BC,)
+        count = count_smem[0]        # () int32, scalar memory
+        if wtile:  # window-tiled step: resident tests bounded at T x BC
+            new = _tiled_block_step(x, xm, count, win_ref, d=d,
+                                    block_c=block_c, wcap=wcap,
+                                    wtile=wtile)
+        else:      # the whole resident window tested at once
+            w = win_ref[...]         # (D_PAD, W)
+            domw = _window_dominated(w, x, d=d)
+            pos, kept = _keep_slots(x, xm, domw, count, d=d,
+                                    block_c=block_c)
+            win_ref[...] = _append(x, pos, 0, wcap, w)
+            new = count + kept
+        count_smem[0] = new
 
-    if wtile:  # window-tiled step: resident tests bounded at T x BC
-        new = _tiled_block_step(x, xm, count, win_ref, d=d,
-                                block_c=block_c, wcap=wcap, wtile=wtile)
-    else:      # the whole resident window tested at once
-        w = win_ref[...]         # (D_PAD, W)
-        domw = _window_dominated(w, x, d=d)
-        pos, kept = _keep_slots(x, xm, domw, count, d=d,
-                                block_c=block_c)
-        win_ref[...] = _append(x, pos, 0, wcap, w)
-        new = count + kept
-    count_smem[0] = new
-    count_ref[...] = jnp.full(count_ref.shape, new, jnp.int32)
+    count_ref[...] = jnp.full(count_ref.shape, count_smem[0], jnp.int32)
 
 
 @functools.partial(
@@ -214,6 +227,7 @@ def _sfs_sweep_kernel(cands_ref, mask_ref, win_ref, count_ref, count_smem,
 def sfs_sweep_pallas(
     cands_t: jnp.ndarray,
     mask: jnp.ndarray,
+    nblk: jnp.ndarray,
     *,
     block_c: int,
     wcap: int,
@@ -229,6 +243,11 @@ def sfs_sweep_pallas(
         the sentinel coordinate; N % block_c == 0.  Attribute rows past
         the true d are zero (inert for the comparisons, never extracted).
       mask: (P, N) int32 row validity (0 = padding / invalid).
+      nblk: (P,) int32 candidate blocks to sweep per partition: every
+        block from ``nblk[i]`` on must hold no valid row of partition
+        ``i`` (`repro.kernels.sfs.ops.sweep_blocks` gives the least such
+        bound).  Those blocks would keep nothing, so they are neither
+        fetched nor tested, and the result is that of the full sweep.
       block_c: candidate block (grid step) size.
       wcap: window capacity in rows (a multiple of the dominance block by
         construction in the caller).
@@ -250,6 +269,7 @@ def sfs_sweep_pallas(
     assert pd_pad % D_PAD == 0, pd_pad
     p = pd_pad // D_PAD
     assert mask.shape == (p, n), (mask.shape, p, n)
+    assert nblk.shape == (p,), (nblk.shape, p)
     assert n % block_c == 0, (n, block_c)
     assert wtile == 0 or wcap % wtile == 0, (wcap, wtile)
     d = D_PAD  # attribute rows are padded/inert; unroll over all of them
@@ -258,31 +278,46 @@ def sfs_sweep_pallas(
                                wcap=wcap, wtile=wtile, sentinel=sentinel)
     vmem = sweep_vmem_bytes(block_c=block_c, wcap=wcap, wtile=wtile,
                             itemsize=jnp.dtype(cands_t.dtype).itemsize)
+
+    def cblk(i, j, nblk_ref):
+        # a skipped step revisits the last swept block: no new fetch
+        return jnp.minimum(j, jnp.maximum(nblk_ref[i] - 1, 0))
+
     win_t, count = pl.pallas_call(
         kernel,
-        grid=(p, n // block_c),
-        in_specs=[
-            pl.BlockSpec((D_PAD, block_c), lambda i, j: (i, j)),
-            # a squeezed partition dim keeps the block's last two dims
-            # (1, BC) legal for any P (the second-minor equals the array's)
-            pl.BlockSpec((pl.squeezed, 1, block_c), lambda i, j: (i, 0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((D_PAD, wcap), lambda i, j: (i, 0)),
-            pl.BlockSpec((pl.squeezed, 1, LANE), lambda i, j: (i, 0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            # the per-partition block bounds sit in scalar memory before
+            # the grid starts, where the index maps can read them
+            num_scalar_prefetch=1,
+            grid=(p, n // block_c),
+            in_specs=[
+                pl.BlockSpec((D_PAD, block_c),
+                             lambda i, j, nb: (i, cblk(i, j, nb))),
+                # a squeezed partition dim keeps the block's last two
+                # dims (1, BC) legal for any P (the second-minor equals
+                # the array's)
+                pl.BlockSpec((pl.squeezed, 1, block_c),
+                             lambda i, j, nb: (i, 0, cblk(i, j, nb))),
+            ],
+            out_specs=[
+                pl.BlockSpec((D_PAD, wcap), lambda i, j, nb: (i, 0)),
+                pl.BlockSpec((pl.squeezed, 1, LANE),
+                             lambda i, j, nb: (i, 0, 0)),
+            ],
+            # the running count is a scalar: it lives in scalar memory
+            # and is broadcast into one lane row of the count output per
+            # step
+            scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((pd_pad, wcap), cands_t.dtype),
             jax.ShapeDtypeStruct((p, 1, LANE), jnp.int32),
         ],
-        # the running count is a scalar: it lives in scalar memory and is
-        # broadcast into one lane row of the count output per step
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes(vmem)),
         name="sfs_sweep",
         interpret=interpret,
-    )(cands_t, mask.reshape(p, 1, n))
+    )(nblk.astype(jnp.int32), cands_t, mask.reshape(p, 1, n))
     return win_t, count[:, 0, 0]
 
 

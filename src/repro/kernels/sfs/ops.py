@@ -58,7 +58,8 @@ from repro.kernels.backend import KernelSpec, resolve_spec
 from repro.kernels.sfs import kernel as _kernel
 from repro.kernels.sfs import ref as _ref
 
-__all__ = ["sfs_sweep", "tpu_geometry", "traced_geometries", "TPU_WTILE"]
+__all__ = ["sfs_sweep", "sweep_blocks", "tpu_geometry", "traced_geometries",
+           "TPU_WTILE"]
 
 
 def _sweep_one_jnp(pts_s, mask_s, *, block: int, wcap: int, sentinel,
@@ -255,10 +256,23 @@ def traced_geometries() -> list[dict]:
     return list(_GEOMETRIES)
 
 
+def sweep_blocks(mask_s: jnp.ndarray, block: int) -> jnp.ndarray:
+    """(..., P) int32: the candidate blocks the Pallas sweep runs on each
+    partition of a (..., P, npad) validity mask — up to and including the
+    last block that holds a valid row, 0 for a partition with none.  Read
+    from the mask itself, so it is exact whatever the order of the rows;
+    the blocks after it keep nothing."""
+    npad = mask_s.shape[-1]
+    last = jnp.max(jnp.where(mask_s, jnp.arange(1, npad + 1, dtype=jnp.int32),
+                             0), axis=-1)
+    return (last + block - 1) // block
+
+
 def _sweep_pallas(pts_s, mask_s, *, block: int, wcap: int, wtile: int,
                   sentinel, interpret: bool):
     """Pack the sorted batch into the TPU kernel's transposed layout,
-    run the one-grid sweep, and unpack.  Compiled (not interpret), the
+    run the one-grid sweep up to each partition's last valid block
+    (`sweep_blocks`), and unpack.  Compiled (not interpret), the
     geometry is `tpu_geometry`'s; interpret mode runs the requested one
     so CPU tests can exercise any tile."""
     p, npad, d = pts_s.shape
@@ -279,14 +293,33 @@ def _sweep_pallas(pts_s, mask_s, *, block: int, wcap: int, wtile: int,
         vmem_limit=_kernel.vmem_limit_bytes(_kernel.sweep_vmem_bytes(
             block_c=block, wcap=wcap, wtile=wtile,
             itemsize=jnp.dtype(pts_s.dtype).itemsize))))
-    cands_t = _pack_transposed(pts_s, _kernel.D_PAD)
-    mask2d = mask_s.astype(jnp.int32)
-    win_t, count = _kernel.sfs_sweep_pallas(
-        cands_t, mask2d, block_c=block, wcap=wcap, wtile=wtile,
-        sentinel=float(sentinel), interpret=interpret)
-    window = jnp.swapaxes(
-        win_t.reshape(p, _kernel.D_PAD, wcap)[:, :d, :], 1, 2)
-    return window, _packed_mask(count, wcap), count
+
+    @jax.custom_batching.custom_vmap
+    def sweep(pts_s, mask_s):
+        p = pts_s.shape[0]
+        cands_t = _pack_transposed(pts_s, _kernel.D_PAD)
+        win_t, count = _kernel.sfs_sweep_pallas(
+            cands_t, mask_s.astype(jnp.int32), sweep_blocks(mask_s, block),
+            block_c=block, wcap=wcap, wtile=wtile,
+            sentinel=float(sentinel), interpret=interpret)
+        window = jnp.swapaxes(
+            win_t.reshape(p, _kernel.D_PAD, wcap)[:, :d, :], 1, 2)
+        return window, _packed_mask(count, wcap), count
+
+    @sweep.def_vmap
+    def _fold(q, batched, pts_s, mask_s):
+        # partitions are independent: a batch of Q (P, npad) sweeps is
+        # one (Q * P, npad) sweep, so vmap keeps a single launch (Pallas
+        # would otherwise loop over the batch, one launch per query,
+        # since the prefetched block bounds are batched)
+        pts_s, mask_s = (x if b else jnp.broadcast_to(x, (q, *x.shape))
+                         for x, b in zip((pts_s, mask_s), batched))
+        outs = sweep(pts_s.reshape(-1, *pts_s.shape[2:]),
+                     mask_s.reshape(-1, mask_s.shape[-1]))
+        return (tuple(o.reshape(q, -1, *o.shape[1:]) for o in outs),
+                (True, True, True))
+
+    return sweep(pts_s, mask_s)
 
 
 def _packed_mask(count, wcap: int):

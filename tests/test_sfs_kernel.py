@@ -9,8 +9,11 @@ import numpy as np
 import pytest
 from _hypothesis_compat import example, given, settings, st
 
+from repro.core.dominance import SENTINEL
 from repro.core.sfs import block_sfs, local_skyline_batch, naive_skyline_mask
 from repro.kernels.backend import KernelSpec, resolve_spec
+from repro.kernels.sfs import sfs_sweep
+from repro.kernels.sfs.ops import sweep_blocks, tpu_geometry
 
 # 'interpret' runs the Pallas kernel body in interpret mode — the CPU
 # validation path for the TPU sweep; 'jnp' is the fused single-dispatch
@@ -110,17 +113,93 @@ def test_overflow_via_block_sfs_wrapper(impl):
     _assert_bitwise_equal(sky, ref, f"impl={impl} (wrapper, overflow)")
 
 
-@pytest.mark.parametrize("impl", SWEEP_IMPLS)
-def test_all_masked_and_empty_partitions(impl):
-    rng = np.random.default_rng(3)
-    pts = jnp.asarray(rng.random((2, 64, 3)), jnp.float32)
-    mask = jnp.zeros((2, 64), jnp.bool_).at[1, :5].set(True)
-    want = local_skyline_batch(pts, mask, capacity=16, block=16,
-                               impl="perpair")
-    got = local_skyline_batch(pts, mask, capacity=16, block=16, impl=impl)
-    _assert_bitwise_equal(got, want, f"impl={impl} (masked)")
-    assert int(got.count[0]) == 0
-    assert not bool(got.mask[0].any())
+def _tail_case(case):
+    """A presorted batch for `sfs_sweep` whose valid rows end early in the
+    padded rows (the tails the Pallas sweep skips):
+    ``(pts, mask, capacity, block, wtile)``.  Coordinates hold -0.0 so
+    the bit-exact copy is checked too."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    p, nblk, block, d = {"tail5": (2, 40, 32, 4), "tail5_tiled": (2, 40, 32, 4),
+                         "empty_partition": (3, 8, 32, 3),
+                         "masked_mid": (2, 10, 32, 3),
+                         "overflow": (2, 40, 32, 2),
+                         "chip_geometry": (2, 40, 128, 4)}[case]
+    n = nblk * block
+    pts = rng.integers(0, 6, (p, n, d)) / 6
+    if case == "overflow":  # an antichain: every valid row is kept
+        xs = rng.random((p, n))
+        pts = np.stack([xs, 1.0 - xs], axis=-1)
+    if case == "empty_partition":
+        mask = np.ones((p, n), bool)
+        mask[1] = False
+    else:
+        mask = rng.random((p, n)) < 0.05
+    pts = np.where(pts == 0, -0.0, pts).astype(np.float32)
+    # SFS presort: valid rows first, by a strictly monotone score
+    order = np.argsort(np.where(mask, pts.sum(-1), np.inf), axis=1,
+                       kind="stable")
+    pts = np.take_along_axis(pts, order[..., None], 1)
+    mask = np.take_along_axis(mask, order, 1)
+    if case == "masked_mid":
+        # whole invalid blocks between valid ones: the last valid block
+        # of partition 0 lies after them, partition 1 ends in block 9
+        mask[0] = False
+        mask[0, :block // 2] = mask[0, 3 * block:3 * block + 5] = True
+        mask[1, :] = np.arange(n) % 7 == 0
+    pts = np.where(mask[..., None], pts, np.float32(SENTINEL))
+    cap, wtile = {"tail5": (256, 0), "tail5_tiled": (256, 64),
+                  "empty_partition": (128, 32), "masked_mid": (64, 0),
+                  "overflow": (32, 16), "chip_geometry": (1024, 512)}[case]
+    return jnp.asarray(pts), jnp.asarray(mask), cap, block, wtile
+
+
+TAIL_CASES = ["tail5", "tail5_tiled", "empty_partition", "masked_mid",
+              "overflow", "chip_geometry"]
+
+
+@pytest.mark.parametrize("impl,case", [
+    *((impl, None) for impl in SWEEP_IMPLS),
+    *(pytest.param("interpret", c, id=f"interpret-{c}")
+      for c in TAIL_CASES)])
+def test_all_masked_and_empty_partitions(impl, case):
+    if case is None:
+        rng = np.random.default_rng(3)
+        pts = jnp.asarray(rng.random((2, 64, 3)), jnp.float32)
+        mask = jnp.zeros((2, 64), jnp.bool_).at[1, :5].set(True)
+        want = local_skyline_batch(pts, mask, capacity=16, block=16,
+                                   impl="perpair")
+        got = local_skyline_batch(pts, mask, capacity=16, block=16,
+                                  impl=impl)
+        _assert_bitwise_equal(got, want, f"impl={impl} (masked)")
+        assert int(got.count[0]) == 0
+        assert not bool(got.mask[0].any())
+        return
+    # the Pallas sweep stops each partition at its last valid block
+    pts, mask, cap, block, wtile = _tail_case(case)
+    nblk = np.asarray(sweep_blocks(mask, block))
+    valid_rows = np.asarray(mask)
+    assert (nblk * block < mask.shape[1]).any() or case == "masked_mid"
+    for i, nb in enumerate(nblk):
+        assert not valid_rows[i, nb * block:].any()
+        assert nb == 0 or valid_rows[i, (nb - 1) * block:nb * block].any()
+    if case == "chip_geometry":  # the compiled sweep's own geometry
+        assert tpu_geometry(block, mask.shape[1], cap, 0) == (block, wtile)
+    kw = dict(block=block, wcap=cap, sentinel=float(SENTINEL))
+    want = sfs_sweep(pts, mask, spec="perpair", **kw)
+    got = sfs_sweep(pts, mask, spec=impl, wtile=wtile, **kw)
+    for g, w, name in zip(got, want, ("window", "wmask", "count")):
+        np.testing.assert_array_equal(
+            np.asarray(g).view(np.int32) if name == "window"
+            else np.asarray(g), np.asarray(w).view(np.int32)
+            if name == "window" else np.asarray(w),
+            err_msg=f"{name} differs impl={impl} case={case}")
+    if case == "empty_partition":
+        assert int(want[2][1]) == 0 and int(want[2][0]) > 0
+    if case == "overflow":
+        assert (np.asarray(want[2]) > cap).all()
+    else:  # a -0.0 member came through the window unchanged
+        win = np.asarray(want[0])[np.asarray(want[1])]
+        assert ((win == 0) & np.signbit(win)).any()
 
 
 def test_wide_d_on_jnp_sweep():
@@ -360,3 +439,46 @@ def test_sweep_under_vmap_and_jit():
         return f(pts, mask)
 
     _assert_bitwise_equal(run("jnp"), run("perpair"), "vmap+jit")
+
+
+def _eqns(jaxpr, in_loop=False):
+    """Every equation of a jaxpr and its sub-jaxprs, with whether it runs
+    inside a ``while`` loop."""
+    for e in jaxpr.eqns:
+        yield e, in_loop
+        for v in e.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub, in_loop or e.primitive.name
+                                     == "while")
+
+
+def test_vmapped_pallas_sweep_is_one_launch():
+    """Batched callers (the engine, the batched chunk inserts, the
+    windowed merge) vmap the sweep over queries: the batch folds into the
+    partition axis, so ONE kernel sweeps every query and partition — not
+    a loop of per-query launches — bit-identical to the reference, also
+    nested and with an unbatched operand."""
+    rng = np.random.default_rng(23)
+    pts = jnp.asarray(rng.random((4, 2, 96, 3)), jnp.float32)  # (Q, P, n, d)
+    mask = jnp.asarray(rng.random((4, 2, 96)) < 0.3)
+
+    def sweep(impl):
+        return lambda x, m: local_skyline_batch(x, m, capacity=64, block=32,
+                                                impl=impl)
+
+    f = jax.vmap(sweep("interpret"))
+    eqns = list(_eqns(jax.make_jaxpr(f)(pts, mask).jaxpr))
+    launches = [(e, loop) for e, loop in eqns
+                if e.primitive.name == "pallas_call"]
+    assert len(launches) == 1 and not launches[0][1]
+    assert launches[0][0].params["grid_mapping"].grid == (4 * 2, 96 // 32)
+    assert not any(e.primitive.name == "while" for e, _ in eqns)
+
+    want = jax.jit(jax.vmap(sweep("perpair")))(pts, mask)
+    _assert_bitwise_equal(jax.jit(f)(pts, mask), want, "vmap (pallas)")
+    nested = jax.jit(jax.vmap(f, in_axes=(0, None)))(
+        jnp.stack([pts, pts]), mask)
+    _assert_bitwise_equal(jax.tree.map(lambda a: a[1], nested), want,
+                          "nested vmap (pallas)")

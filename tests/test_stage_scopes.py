@@ -230,3 +230,47 @@ def test_dispatch_span_in_the_profile(tmp_path):
              for line in plane.lines for e in line.events
              if e.name == parallel.DISPATCH_SPAN]
     assert len(spans) == 1 and spans[0].duration_ns > 0
+
+
+def test_sweep_block_counters_match_the_kernel_bound(monkeypatch):
+    """`sweep_blocks` counts the candidate blocks the local sweep runs —
+    each partition's up to its last valid row after the representative
+    filter, read from the mask the sweep receives — and
+    `merge_sweep_blocks` those of the sequential merge's sweep over the
+    compacted union."""
+    import numpy as np
+    from repro.core import sfs
+    from repro.kernels.sfs.ops import sweep_blocks
+
+    seen = []
+    real = sfs.sfs_sweep
+
+    def spy(pts_p, mask_p, **kw):
+        seen.append((np.asarray(mask_p), kw["block"]))
+        return real(pts_p, mask_p, **kw)
+
+    monkeypatch.setattr(sfs, "sfs_sweep", spy)
+    cfg = SkyConfig(**CFG)
+    pts = jax.random.uniform(jax.random.PRNGKey(5), (N, D))
+    key = jax.random.PRNGKey(0)
+    buckets, meta, _ = parallel.partition_stage(pts, jnp.ones(N, bool),
+                                                cfg, key)
+    sky, s2 = parallel.local_stage(buckets.points, buckets.mask, cfg,
+                                   key=jax.random.fold_in(key, 1))
+    _, s3 = parallel.merge_stage(sky, meta, cfg)
+    (local_mask, block), (merge_mask, merge_block) = seen
+    assert block == merge_block == CFG["block"]
+
+    valid = local_mask.sum(axis=1)
+    assert int(s2["rep_filter_dropped"]) > 0
+    assert int(s2["sweep_blocks"]) == int(np.sum(-(-valid // block)))
+    assert int(s2["sweep_blocks"]) == int(np.sum(sweep_blocks(local_mask,
+                                                              block)))
+    padded = local_mask.shape[0] * local_mask.shape[1] // block
+    assert int(s2["sweep_blocks"]) < padded
+
+    union = int(s3["union_size"])
+    assert int(merge_mask.sum()) == union
+    assert int(s3["merge_sweep_blocks"]) == -(-union // block)
+    assert int(s3["merge_sweep_blocks"]) == int(np.sum(sweep_blocks(
+        merge_mask, block)))

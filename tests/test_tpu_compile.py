@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.dominance.kernel import dominated_mask_pallas
+from repro.kernels.sfs import kernel as sweep_kernel
 from repro.kernels.sfs import ops as sweep_ops
 from repro.kernels.sfs.kernel import (D_PAD, sfs_sweep_pallas,
                                       sweep_vmem_bytes, vmem_limit_bytes)
@@ -60,9 +61,11 @@ def _compile(fn, one_chip, *shapes):
 
 def _sweep_hlo(one_chip, *, p, n, wcap, block_c, wtile):
     return _compile(
-        lambda c, m: sfs_sweep_pallas(c, m, block_c=block_c, wcap=wcap,
-                                      wtile=wtile, sentinel=1e30),
-        one_chip, ((p * D_PAD, n), jnp.float32), ((p, n), jnp.int32))
+        lambda c, m, nb: sfs_sweep_pallas(c, m, nb, block_c=block_c,
+                                          wcap=wcap, wtile=wtile,
+                                          sentinel=1e30),
+        one_chip, ((p * D_PAD, n), jnp.float32), ((p, n), jnp.int32),
+        ((p,), jnp.int32))
 
 
 def test_sweep_untiled_compiles(one_chip):
@@ -88,6 +91,42 @@ def test_sweep_tiled_compiles(one_chip, n, wcap):
     text = _sweep_hlo(one_chip, p=8 if n == 4096 else 1, n=n, wcap=wcap,
                       block_c=block_c, wtile=wtile)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("p,n,wcap", [
+    pytest.param(8, 256256, 16384, id="hou7-local"),    # 8 buckets of 256,161
+    pytest.param(1, 131072, 131072, id="hou7-merge"),   # the union's buffer
+])
+def test_bounded_sweep_compiles_within_its_vmem_bound(one_chip,
+                                                      monkeypatch, p, n,
+                                                      wcap):
+    """The scalar-prefetched grid (each partition's block bound read by
+    the candidate index maps) at `hou7`'s local and merge geometries,
+    with Mosaic's scoped VMEM held to the kernel's own estimate rather
+    than the larger compiler default."""
+    block_c, wtile = sweep_ops.tpu_geometry(256, n, wcap, 0)
+    assert (block_c, wtile) == (256, sweep_ops.TPU_WTILE)
+    est = sweep_vmem_bytes(block_c=block_c, wcap=wcap, wtile=wtile)
+    monkeypatch.setattr(sweep_kernel, "vmem_limit_bytes", lambda e: e)
+    text = _compile(
+        lambda c, m, nb: sfs_sweep_pallas.__wrapped__(
+            c, m, nb, block_c=block_c, wcap=wcap, wtile=wtile,
+            sentinel=1e30),
+        one_chip, ((p * D_PAD, n), jnp.float32), ((p, n), jnp.int32),
+        ((p,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_vmapped_sweep_compiles_to_one_kernel(one_chip):
+    """The engine's small-query path vmaps the sweep over queries: with
+    the block bounds prefetched per (query, partition), the compiled
+    program still holds one kernel launch and no loop over the batch."""
+    text = _compile(
+        jax.vmap(lambda x, m: sweep_ops.sfs_sweep(
+            x, m, block=256, wcap=2048, sentinel=1e30, spec="pallas")),
+        one_chip, ((4, 8, 8192, 7), jnp.float32), ((4, 8, 8192), jnp.bool_))
+    assert text.count("tpu_custom_call") == 1
+    assert " while(" not in text
 
 
 @pytest.mark.parametrize("lower_tri", [False, True])
